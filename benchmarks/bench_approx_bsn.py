@@ -33,6 +33,7 @@ from repro.core import hwmodel, si
 from repro.core.bsn import (ApproxBSNSpec, StageSpec, SubSampleSpec,
                             default_approx_spec)
 from repro.kernels import autotune, dispatch, ops
+from repro.launch.compile_cache import use_compile_cache
 
 from .bench_bsn_cost import measured_mse
 
@@ -197,6 +198,7 @@ def main() -> None:
                          "BENCH_approx_bsn.json")
     ap.add_argument("--out", default=str(ROOT / "BENCH_approx_bsn.json"))
     args = ap.parse_args()
+    use_compile_cache()
     rows = kernel_sweep(rows_batch=64) if args.smoke else run()
     if args.smoke:
         results = {n: {"us_per_call": us, "derived": d}

@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.configs import LayerSpec, get_arch
 from repro.kernels import autotune
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import decode_step, init_params, prefill
 from repro.serving import SamplingParams, ServeEngine
 from repro.serving.engine import _pad_prefill_cache
@@ -506,6 +507,7 @@ def main() -> None:
                          "measured point (CI gate; local bar is 3x at 8 "
                          "slots, CI uses margin for runner noise)")
     args = ap.parse_args()
+    use_compile_cache()
     if sum((args.sharded, args.recurrent, args.paged_kernel,
             args.kv_format, args.spec_decode)) > 1:
         ap.error("--sharded / --recurrent / --paged-kernel / --kv-format "

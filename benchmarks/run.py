@@ -14,6 +14,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 BENCHES = [
     "fsm_vs_bsn",            # Fig 1
     "quant_ablation",        # Table III
@@ -32,6 +34,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names")
     args = ap.parse_args()
+    use_compile_cache()
     names = args.only.split(",") if args.only else BENCHES
 
     print("name,us_per_call,derived")

@@ -146,7 +146,10 @@ def eqn_provenance(eqn) -> str:
     for f in frames:
         fn = str(f.file_name).replace("\\", "/")
         if "/repro/" in fn and "/analysis/" not in fn:
-            return f"{fn.split('/repro/')[-1]}:{f.function_name}"
+            # key on the bare function name: newer jax reports qualified
+            # names such as ``_decode_kernel.<locals>._accumulate``
+            name = str(f.function_name).rsplit(".", 1)[-1]
+            return f"{fn.split('/repro/')[-1]}:{name}"
     return "<external>"
 
 

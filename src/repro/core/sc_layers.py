@@ -18,6 +18,7 @@ bitstream path) is asserted in tests/test_sc_layers.py.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import Callable
 
@@ -71,6 +72,10 @@ class SCQuantConfig:
 
 
 SC_OFF = SCQuantConfig(mode="none")
+
+# HBM bound on one block of sc_linear_int_approx's partial-product
+# counts: one granite-3-2b decode lane's lm_head alone is 405 MB of them
+_COUNTS_BYTES = 256 * 2 ** 20
 
 
 # ---------------------------------------------------------------------------
@@ -229,10 +234,29 @@ def sc_linear_int_approx(int_params: dict, x_q: jax.Array,
     if spec.in_bsl != act_bsl:
         raise ValueError(f"spec.in_bsl={spec.in_bsl} != act_bsl={act_bsl}")
     half = act_bsl // 2
-    # partial products, one thermometer code per (input, channel) pair
-    prod_q = x_q[..., :, None].astype(jnp.int32) * w_int.astype(jnp.int32)
-    counts = jnp.swapaxes(prod_q, -1, -2) + half   # (..., N, K) in [0, bsl]
-    out = approx_bsn(counts, spec, cycles=cycles, backend=backend)
+    n = w_int.shape[1]
+
+    def accumulate(xq):
+        # partial products, one thermometer code per (input, channel)
+        prod_q = xq[..., :, None].astype(jnp.int32) \
+            * w_int.astype(jnp.int32)
+        counts = jnp.swapaxes(prod_q, -1, -2) + half  # (..., N, K), [0, bsl]
+        return approx_bsn(counts, spec, cycles=cycles, backend=backend)
+
+    # the (tokens, N, K) int32 counts are materialized for the BSN
+    # kernel; past _COUNTS_BYTES they are built one token block at a
+    # time (the adder is per row, so blocking cannot change a result)
+    lead = x_q.shape[:-1]
+    rows = math.prod(lead)
+    per_block = max(1, _COUNTS_BYTES // (4 * n * k))
+    if rows <= per_block:
+        out = accumulate(x_q)
+    else:
+        blocks = -(-rows // per_block)
+        xf = jnp.pad(x_q.reshape(rows, k),
+                     ((0, blocks * per_block - rows), (0, 0)))
+        out = jax.lax.map(accumulate, xf.reshape(blocks, per_block, k))
+        out = out.reshape(blocks * per_block, n)[:rows].reshape(*lead, n)
     sum_q = spec.scale * (out - cycles * spec.out_bsl // 2)
     return _si_epilogue(int_params, sum_q)
 

@@ -108,8 +108,8 @@ def _paged_case(seed, S, Hkv, D, page, maxp, kv_format="fp"):
     check_kv_format(kv_format)
     rng = np.random.default_rng(seed)
     n = S * maxp + 1
-    kf = jnp.asarray(rng.standard_normal((n, page, Hkv, D)), jnp.float32)
-    vf = jnp.asarray(rng.standard_normal((n, page, Hkv, D)), jnp.float32)
+    kf = jnp.asarray(rng.standard_normal((n, Hkv, page, D)), jnp.float32)
+    vf = jnp.asarray(rng.standard_normal((n, Hkv, page, D)), jnp.float32)
     aux = {}
     if kv_format == "fp":
         kp, vp = kf, vf
@@ -174,7 +174,7 @@ def autotune_paged_prefill(G: int, C: int, Hkv: int, Gq: int, D: int,
 
     def plan_for(block_q):
         return paged_attn_prefill_plan(
-            G=G, C=C, Hkv=Hkv, Gq=Gq, D=D, page=page, start=start,
+            G=G, C=C, Hkv=Hkv, Gq=Gq, D=D, page=page,
             num_pages=kp.shape[0], table_width=tables.shape[1],
             block_q=block_q, kv_format=kv_format)
 

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from .plan import compiler_params
 
 __all__ = ["bsn_sort_pallas"]
 
@@ -60,18 +60,12 @@ def bsn_sort_pallas(x: jax.Array, *, descending: bool = True,
     assert r % block_r == 0, (r, block_r)
     kernel = functools.partial(_sort_kernel, length=length,
                                descending=descending)
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel",))
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",))
     return pl.pallas_call(
         kernel,
         grid=(r // block_r,),
         in_specs=[pl.BlockSpec((block_r, length), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_r, length), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, length), x.dtype),
-        compiler_params=compiler_params,
+        compiler_params=compiler_params(("parallel",)),
         interpret=interpret,
     )(x)

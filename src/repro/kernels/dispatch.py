@@ -28,6 +28,7 @@ kernel without importing repro.kernels themselves.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import math
 from dataclasses import dataclass
@@ -47,16 +48,32 @@ from .paged_attention import (paged_attn_decode_pallas,
                               paged_attn_decode_plan,
                               paged_attn_prefill_pallas,
                               paged_attn_prefill_plan)
+from .plan import DEFAULT_VMEM_BUDGET, estimate_vmem
 
 __all__ = ["BACKENDS", "select_backend", "set_default_backend",
            "get_default_backend", "backend_scope", "approx_bsn",
            "spec_stages", "attn_backend_scope", "set_attn_backend",
            "get_attn_backend", "paged_attn_decode", "paged_attn_prefill",
+           "resolved_backends", "clear_resolved", "bsn_block_rows",
            "KernelEntry", "KERNEL_REGISTRY"]
 
 BACKENDS = ("pallas", "pallas-interpret", "reference")
 
 _default_backend: str | None = None
+
+# trace-time log of every resolution, (kernel, backend) -> calls: the
+# chip smoke reads it to prove the compiled kernels served
+_resolved: collections.Counter = collections.Counter()
+
+
+def resolved_backends() -> dict[tuple[str, str], int]:
+    """(kernel, backend) -> number of traced calls resolved that way
+    since the last :func:`clear_resolved`."""
+    return dict(_resolved)
+
+
+def clear_resolved() -> None:
+    _resolved.clear()
 
 
 def set_default_backend(backend: str | None) -> None:
@@ -116,6 +133,33 @@ def spec_stages(spec: ApproxBSNSpec) -> tuple[tuple[int, int, int], ...]:
     return tuple((s.group, s.sub.clip, s.sub.stride) for s in spec.stages)
 
 
+def bsn_block_rows(rows: int, spec: ApproxBSNSpec, block_r: int,
+                   cycles: int = 1) -> int:
+    """The BSN kernel's row tile for ``rows`` codes of ``spec.width``:
+    ``block_r`` clamped to the (pow2-padded) row count, then halved
+    until the plan's own VMEM estimate — the double-buffered
+    ``(block_r, width)`` int32 tile — fits ``DEFAULT_VMEM_BUDGET``.  At
+    granite's down projection (K=8192) the default 256 rows would need
+    16 MiB of tiles, past a v5e core's scoped VMEM."""
+    block_r = min(block_r, max(8, 1 << (rows - 1).bit_length()))
+    stages = spec_stages(spec)
+
+    def est(br):
+        if cycles == 1:
+            plan = approx_bsn_plan(rows=br, width=spec.width,
+                                   in_bsl=spec.in_bsl, stages=stages,
+                                   block_r=br)
+        else:
+            plan = approx_bsn_temporal_plan(
+                rows=br, width=spec.width, in_bsl=spec.in_bsl,
+                stages=stages, cycles=cycles, block_r=br)
+        return estimate_vmem(plan)
+
+    while block_r > 8 and est(block_r) > DEFAULT_VMEM_BUDGET:
+        block_r //= 2
+    return block_r
+
+
 def approx_bsn(counts: jax.Array, spec: ApproxBSNSpec, *, cycles: int = 1,
                backend: str | None = None, block_r: int = 256,
                min_rows_for_kernel: int = 8) -> jax.Array:
@@ -141,6 +185,7 @@ def approx_bsn(counts: jax.Array, spec: ApproxBSNSpec, *, cycles: int = 1,
         # degenerate grid — the reference returns the empty result with
         # the right trailing shape/dtype regardless of requested backend
         chosen = "reference"
+    _resolved[("approx_bsn", chosen)] += 1
 
     if chosen == "reference":
         if cycles == 1:
@@ -148,7 +193,7 @@ def approx_bsn(counts: jax.Array, spec: ApproxBSNSpec, *, cycles: int = 1,
         return spatial_temporal_counts(counts, spec, cycles)
 
     interpret = chosen == "pallas-interpret"
-    block_r = min(block_r, max(8, 1 << (rows - 1).bit_length()))
+    block_r = bsn_block_rows(rows, spec, block_r, cycles)
     rp = (rows + block_r - 1) // block_r * block_r
     x2 = counts.reshape(rows, total).astype(jnp.int32)
     x2 = jnp.pad(x2, ((0, rp - rows), (0, 0)))
@@ -207,7 +252,7 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array,
                       kv_format: str = "fp",
                       kv_aux: dict | None = None) -> jax.Array:
     """Batched one-token paged decode: (S, Hkv, G, D) queries against the
-    (N, page, Hkv, D) pools through (S, maxp) tables, masked by
+    (N, Hkv, page, D) pools through (S, maxp) tables, masked by
     ``lengths``.  Flash-decoding Pallas kernel on the kernel backends,
     XLA gather oracle (kernels/ref.py) on ``"reference"``.  Compressed
     pools (``kv_format`` "int8"/"sc") pass the parallel scale/residual
@@ -218,6 +263,7 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array,
     chosen = select_backend(S * Hkv * G, backend=backend,
                             min_rows_for_kernel=min_rows_for_kernel,
                             default=_attn_backend)
+    _resolved[("paged_attn_decode", chosen)] += 1
     if chosen == "reference":
         return ref.paged_attn_decode_ref(q, k_pages, v_pages,
                                          page_tables, lengths,
@@ -230,20 +276,21 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array,
 
 def paged_attn_prefill(q: jax.Array, k_pages: jax.Array,
                        v_pages: jax.Array, page_tables: jax.Array,
-                       start: int, *, backend: str | None = None,
+                       start, *, backend: str | None = None,
                        block_q: int = 32,
                        min_rows_for_kernel: int = 8,
                        kv_format: str = "fp",
                        kv_aux: dict | None = None) -> jax.Array:
     """One chunk of paged prefill: (G, C, Hkv, Gq, D) queries at
-    positions ``[start, start+C)`` against every page written so far,
-    causal.  Same backend chain (and ``kv_format``/``kv_aux`` contract)
-    as :func:`paged_attn_decode`."""
+    positions ``[start, start+C)`` (``start`` may be traced) against
+    every page written so far, causal.  Same backend chain (and
+    ``kv_format``/``kv_aux`` contract) as :func:`paged_attn_decode`."""
     G, C, Hkv, Gq, _ = q.shape
     aux = kv_aux or {}
     chosen = select_backend(G * C * Hkv * Gq, backend=backend,
                             min_rows_for_kernel=min_rows_for_kernel,
                             default=_attn_backend)
+    _resolved[("paged_attn_prefill", chosen)] += 1
     if chosen == "reference":
         return ref.paged_attn_prefill_ref(q, k_pages, v_pages,
                                           page_tables, start,
@@ -280,10 +327,10 @@ class KernelEntry:
 
 def _bsn_case(rows: int, width: int, block_r: int,
               cycles: int = 1) -> tuple[str, dict]:
-    """Mirror dispatch.approx_bsn's clamp-then-pad of (rows, block_r)."""
-    br = min(block_r, max(8, 1 << (rows - 1).bit_length()))
-    rp = (rows + br - 1) // br * br
+    """Mirror dispatch.approx_bsn's fit-then-pad of (rows, block_r)."""
     spec = default_approx_spec(width, 2)
+    br = bsn_block_rows(rows, spec, block_r, cycles)
+    rp = (rows + br - 1) // br * br
     kw = dict(rows=rp, width=width, in_bsl=spec.in_bsl,
               stages=spec_stages(spec), block_r=br)
     if cycles > 1:
@@ -328,9 +375,11 @@ def _decode_cases() -> tuple[tuple[str, dict], ...]:
 
 
 def _prefill_cases() -> tuple[tuple[str, dict], ...]:
+    # the chunk start is a runtime scalar; the plan's value model sweeps
+    # it from 0 to the last chunk the table holds
     return tuple(
-        (f"chunk32_start32_bq{bq}",
-         dict(G=4, C=32, Hkv=2, Gq=2, D=16, page=16, start=32,
+        (f"chunk32_table4_bq{bq}",
+         dict(G=4, C=32, Hkv=2, Gq=2, D=16, page=16,
               num_pages=4 * 4 + 1, table_width=4, block_q=bq))
         for bq in (8, 16, 32))
 

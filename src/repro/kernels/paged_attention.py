@@ -35,22 +35,28 @@ window of ``attn_prefill_paged``: q rows are chunk positions
 ``[0, (start + C) / page)``), masked by ``k_pos <= q_pos``.  Blocks of
 ``block_q`` rows carry ``(m, l, acc)`` in VMEM scratch across the page
 loop and normalize on the last page; future pages are skipped per
-q-block (the causal early-exit).
+q-block (the causal early-exit) and never DMA'd.  ``start`` is a
+scalar-prefetch operand, so every chunk of a prompt runs one compiled
+kernel.
+
+Pool layout is head-major: KV (and sc residual) pools are
+``(N, Hkv, page, D)`` and each grid step DMAs one ``(page, D)`` tile —
+block ``(1, 1, page, D)``, whose last two dims are the array's own, as
+Mosaic requires of a block that is not (8, 128)-aligned.  Scale pools
+are ``(N, Hkv, page)`` and one block carries a page's scales for all
+heads, ``(1, Hkv, page)``; the kernel picks its head's row and turns it
+into a ``(page, 1)`` column with exact selects and sums (adding zeros),
+so the dequant multiplies exactly the values ``kv_dequant`` does.
 
 Compressed KV pools (core/kv_quant.py: ``kv_format`` "int8" / "sc")
-dequantize INSIDE the kernels: the per-position scale pools (and the sc
-residual pools) ride the same scalar-prefetch page-table index maps as
-the KV blocks, so each grid step DMAs one page of int8 codes plus its
-scales and reconstructs float K/V in VMEM — the fp window never exists
-in HBM.  The elementwise dequant mirrors ``kv_dequant`` exactly, so the
-kernel-vs-reference differential stays as tight as the fp one.
+dequantize INSIDE the kernels: the scale pools (and the sc residual
+pools) ride the same scalar-prefetch page-table index maps as the KV
+blocks, so each grid step DMAs one page of int8 codes plus its scales
+and reconstructs float K/V in VMEM — the fp window never exists in HBM.
 
-Layout notes for real TPUs: the accumulator blocks put the (small) GQA
-group width G in the lane dimension, so Mosaic pads tiles for the tiny
-serving configs exercised here — fine for correctness-first; the
-autotune sweep (kernels/autotune.py) picks ``num_splits`` / ``block_q``
-per shape.  Interpret mode (`interpret=True`) is bit-for-bit the
-compiled semantics and is what CPU CI runs.
+Interpret mode (`interpret=True`) runs the same kernel bodies and is
+what CPU CI runs; ``tests/test_tpu_compile.py`` compiles both kernels
+for a described TPU v5e at granite-3-2b's widths.
 """
 
 from __future__ import annotations
@@ -69,26 +75,42 @@ __all__ = ["paged_attn_decode_pallas", "paged_attn_prefill_pallas",
            "paged_attn_decode_plan", "paged_attn_prefill_plan"]
 
 _NEG = -1e30
+# q (rows, D) . k (page, D) contracting D of both: the scores matmul
+# without a transpose of k in the kernel
+_QK_DIMS = (((1,), (1,)), ((), ()))
 
 
-def _load_kv_block(kv_format: str, x_ref, s_ref=None, r_ref=None):
-    """One physical page of K or V -> (page, D) float32, dequant fused.
+def _head_scale_column(s_ref, h, page: int) -> jax.Array:
+    """Row ``h`` of the ``(1, Hkv, page)`` scale block as a ``(page, 1)``
+    column.  Both steps select one value and add zeros, so the column
+    holds the stored scales exactly (no matmul, no rounding)."""
+    s = s_ref[0]                                    # (Hkv, page)
+    heads = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    row = jnp.sum(jnp.where(heads == h, s, 0.0), axis=0, keepdims=True)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (page, page), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (page, page), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
-    ``x_ref`` is the (1, page, 1, D) pool block; for compressed formats
-    ``s_ref`` is the parallel (1, page, 1) scale block and ``r_ref`` the
+
+def _load_kv_block(kv_format: str, h, x_ref, s_ref=None, r_ref=None):
+    """One physical page of KV head ``h`` -> (page, D) float32, dequant
+    fused.
+
+    ``x_ref`` is the (1, 1, page, D) pool block; for compressed formats
+    ``s_ref`` is the page's (1, Hkv, page) scale block and ``r_ref`` the
     sc residual block.  The elementwise math mirrors
     core.kv_quant.kv_dequant exactly, so the kernel matches the
     gather-then-dequant reference bit-for-bit per element.
     """
-    raw = x_ref[0, :, 0, :]
+    raw = x_ref[0, 0]                               # (page, D)
     if kv_format == "fp":
         return raw.astype(jnp.float32)
-    sc = s_ref[0, :, 0]                             # (page,)
+    sc = _head_scale_column(s_ref, h, raw.shape[0])   # (page, 1)
     if kv_format == "int8":
-        return raw.astype(jnp.float32) * sc[:, None]
-    fused = (r_ref[0, :, 0, :].astype(jnp.int32)
+        return raw.astype(jnp.float32) * sc
+    fused = (r_ref[0, 0].astype(jnp.int32)
              + raw.astype(jnp.int32) * (1 << SC_SHIFT))
-    return fused.astype(jnp.float32) * (sc * (2.0 ** -SC_SHIFT))[:, None]
+    return fused.astype(jnp.float32) * (sc * (2.0 ** -SC_SHIFT))
 
 
 def _split_aux_refs(kv_format: str, rest, n_tail: int):
@@ -113,6 +135,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                    scale: float, kv_format: str):
     aux, (m_ref, l_ref, acc_ref) = _split_aux_refs(kv_format, rest, 3)
     s = pl.program_id(0)
+    h = pl.program_id(1)
     sp = pl.program_id(2)
     p = pl.program_id(3)
 
@@ -128,20 +151,43 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
     @pl.when(base <= length)                        # page holds live tokens
     def _accumulate():
         q = q_ref[0, 0].astype(jnp.float32)         # (G, D)
-        k = _load_kv_block(kv_format, k_ref, *aux[0::2])   # (page, D)
-        v = _load_kv_block(kv_format, v_ref, *aux[1::2])
-        logits = jnp.dot(q, k.T) / scale            # (G, page)
+        k = _load_kv_block(kv_format, h, k_ref, *aux[0::2])  # (page, D)
+        v = _load_kv_block(kv_format, h, v_ref, *aux[1::2])
+        logits = jax.lax.dot_general(
+            q, k, _QK_DIMS, preferred_element_type=jnp.float32) / scale
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         live = pos <= length                        # (1, page)
-        logits = jnp.where(live, logits, _NEG)
-        m_prev = m_ref[0, 0, 0]                     # (G,)
-        new_m = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-        w = jnp.where(live, jnp.exp(logits - new_m[:, None]), 0.0)
+        logits = jnp.where(live, logits, _NEG)      # (G, page)
+        m_prev = m_ref[0, 0, 0]                     # (G, 1)
+        new_m = jnp.maximum(m_prev, jnp.max(logits, axis=-1,
+                                            keepdims=True))
+        w = jnp.where(live, jnp.exp(logits - new_m), 0.0)
         corr = jnp.exp(m_prev - new_m)
         m_ref[0, 0, 0] = new_m
-        l_ref[0, 0, 0] = l_ref[0, 0, 0] * corr + jnp.sum(w, axis=-1)
-        acc_ref[0, 0, 0] = (acc_ref[0, 0, 0] * corr[:, None]
-                            + jnp.dot(w, v))
+        l_ref[0, 0, 0] = (l_ref[0, 0, 0] * corr
+                          + jnp.sum(w, axis=-1, keepdims=True))
+        acc_ref[0, 0, 0] = (acc_ref[0, 0, 0] * corr
+                            + jnp.dot(w, v,
+                                      preferred_element_type=jnp.float32))
+
+
+def _kv_operands(kv_format: str, num_pages: int, Hkv: int, page: int,
+                 D: int, kv_dtype, kv_index, scale_index) -> list:
+    """The pool operands in kernel order: K, V [, scales [, residuals]].
+    KV/residual blocks are one head's page ``(1, 1, page, D)``; a scale
+    block is one page for all heads ``(1, Hkv, page)``."""
+    kv = dict(shape=(num_pages, Hkv, page, D), dtype=kv_dtype,
+              block=(1, 1, page, D), index_map=kv_index)
+    sc = dict(shape=(num_pages, Hkv, page), dtype=jnp.float32,
+              block=(1, Hkv, page), index_map=scale_index)
+    ops = [BlockOperand("k_pages", **kv), BlockOperand("v_pages", **kv)]
+    if kv_format != "fp":
+        ops += [BlockOperand("k_scale", **sc),
+                BlockOperand("v_scale", **sc)]
+    if kv_format == "sc":
+        ops += [BlockOperand("k_resid", **kv),
+                BlockOperand("v_resid", **kv)]
+    return ops
 
 
 def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
@@ -157,7 +203,9 @@ def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
     proves its bounds/VMEM/revisit properties without tracing it.
     ``num_pages`` is the pool's leading dim (engine pools include the
     reserved trash page), bounding legal page-table entries; ragged
-    worst-case lengths straddle the last page boundary.
+    worst-case lengths straddle the last page boundary.  The per-split
+    partials ``m``/``l`` are ``(G, 1)`` columns and ``acc`` is
+    ``(G, D)``: full trailing dims, legal for every ``num_splits``.
     """
     check_kv_format(kv_format)
     if kv_dtype is None:
@@ -171,30 +219,18 @@ def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
 
     def kv_index(s, h, sp, p, pt, ln):
         del ln
-        return (pt[s, sp * pps + p], 0, h, 0)
+        return (pt[s, sp * pps + p], h, 0, 0)
 
     def scale_index(s, h, sp, p, pt, ln):
-        del ln
-        return (pt[s, sp * pps + p], 0, h)
+        del h, ln
+        return (pt[s, sp * pps + p], 0, 0)
 
-    kv = dict(shape=(num_pages, page, Hkv, D), dtype=kv_dtype,
-              block=(1, page, 1, D), index_map=kv_index)
-    sc = dict(shape=(num_pages, page, Hkv), dtype=jnp.float32,
-              block=(1, page, 1), index_map=scale_index)
-    inputs = [
-        BlockOperand("q", (S, Hkv, G, D), q_dtype, (1, 1, G, D),
-                     lambda s, h, sp, p, pt, ln: (s, h, 0, 0)),
-        BlockOperand("k_pages", **kv),
-        BlockOperand("v_pages", **kv),
-    ]
-    if kv_format != "fp":
-        inputs += [BlockOperand("k_scale", **sc),
-                   BlockOperand("v_scale", **sc)]
-    if kv_format == "sc":
-        inputs += [BlockOperand("k_resid", **kv),
-                   BlockOperand("v_resid", **kv)]
+    inputs = [BlockOperand("q", (S, Hkv, G, D), q_dtype, (1, 1, G, D),
+                           lambda s, h, sp, p, pt, ln: (s, h, 0, 0))]
+    inputs += _kv_operands(kv_format, num_pages, Hkv, page, D, kv_dtype,
+                           kv_index, scale_index)
 
-    part_index = lambda s, h, sp, p, pt, ln: (s, h, sp, 0)  # noqa: E731
+    part_index = lambda s, h, sp, p, pt, ln: (s, h, sp, 0, 0)  # noqa: E731
     max_len = maxp * page
     return LaunchPlan(
         name="paged_attn_decode",
@@ -213,13 +249,12 @@ def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
         ),
         inputs=tuple(inputs),
         outputs=(
-            BlockOperand("m", (S, Hkv, num_splits, G), jnp.float32,
-                         (1, 1, 1, G), part_index),
-            BlockOperand("l", (S, Hkv, num_splits, G), jnp.float32,
-                         (1, 1, 1, G), part_index),
+            BlockOperand("m", (S, Hkv, num_splits, G, 1), jnp.float32,
+                         (1, 1, 1, G, 1), part_index),
+            BlockOperand("l", (S, Hkv, num_splits, G, 1), jnp.float32,
+                         (1, 1, 1, G, 1), part_index),
             BlockOperand("acc", (S, Hkv, num_splits, G, D), jnp.float32,
-                         (1, 1, 1, G, D),
-                         lambda s, h, sp, p, pt, ln: (s, h, sp, 0, 0)),
+                         (1, 1, 1, G, D), part_index),
         ),
         scratch=(),
         kernel=kernel,
@@ -231,6 +266,15 @@ def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
                      "acc": "online-softmax"} if pps > 1 else {}),
         single_output=False,
     )
+
+
+def _aux_operands(kv_format: str, k_scale, v_scale, k_resid, v_resid):
+    ops = []
+    if kv_format != "fp":
+        ops += [k_scale, v_scale]
+    if kv_format == "sc":
+        ops += [k_resid, v_resid]
+    return ops
 
 
 @functools.partial(jax.jit,
@@ -246,11 +290,11 @@ def paged_attn_decode_pallas(q: jax.Array, k_pages: jax.Array,
                              v_resid: jax.Array | None = None) -> jax.Array:
     """Batched one-token paged decode.
 
-    q: (S, Hkv, G, D) grouped queries; k_pages/v_pages: (N, page, Hkv, D)
+    q: (S, Hkv, G, D) grouped queries; k_pages/v_pages: (N, Hkv, page, D)
     pools (already holding the new token at position ``lengths``);
     page_tables: (S, maxp) int32; lengths: (S,) int32.  For compressed
     pools (``kv_format`` "int8"/"sc") the parallel ``k_scale``/``v_scale``
-    (N, page, Hkv) — and for sc the ``k_resid``/``v_resid`` — pools ride
+    (N, Hkv, page) — and for sc the ``k_resid``/``v_resid`` — pools ride
     the SAME page-table index maps as the KV blocks, so each grid step
     DMAs one page of codes + its scales and dequantizes in VMEM: no fp
     pages ever materialize in HBM.  Returns the attention context
@@ -258,7 +302,7 @@ def paged_attn_decode_pallas(q: jax.Array, k_pages: jax.Array,
     """
     check_kv_format(kv_format)
     S, Hkv, G, D = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     maxp = page_tables.shape[1]
     plan = paged_attn_decode_plan(
         S=S, Hkv=Hkv, G=G, D=D, page=page, maxp=maxp,
@@ -270,13 +314,10 @@ def paged_attn_decode_pallas(q: jax.Array, k_pages: jax.Array,
         # (which is < maxp*page by construction) so masking kills them
         page_tables = jnp.pad(page_tables, ((0, 0), (0, maxp_pad - maxp)))
 
-    aux_ops = []
-    if kv_format != "fp":
-        aux_ops += [k_scale, v_scale]
-    if kv_format == "sc":
-        aux_ops += [k_resid, v_resid]
+    aux_ops = _aux_operands(kv_format, k_scale, v_scale, k_resid, v_resid)
     m, l, acc = call_plan(plan, (page_tables, lengths, q, k_pages,
                                  v_pages, *aux_ops), interpret=interpret)
+    m, l = m[..., 0], l[..., 0]                       # (S, Hkv, splits, G)
 
     # flash-decoding LSE merge across splits (exact: splits with no live
     # pages carry m=-1e30, l=0 and weigh zero)
@@ -292,12 +333,15 @@ def paged_attn_decode_pallas(q: jax.Array, k_pages: jax.Array,
 # prefill: chunk-aligned causal window over the pages written so far
 # ---------------------------------------------------------------------------
 
-def _prefill_kernel(pt_ref, q_ref, k_ref, v_ref,
-                    *rest, bq: int, page: int, n_pg: int,
-                    start: int, scale: float, kv_format: str):
+def _prefill_kernel(pt_ref, st_ref, q_ref, k_ref, v_ref,
+                    *rest, bq: int, page: int, n_pg: int, Hq: int,
+                    Gq: int, scale: float, kv_format: str):
     aux, (o_ref, m_sc, l_sc, acc_sc) = _split_aux_refs(kv_format, rest, 4)
+    bh = pl.program_id(0)
     qi = pl.program_id(1)
     pg = pl.program_id(2)
+    h = (bh % Hq) // Gq                             # this q head's KV head
+    start = st_ref[0]
 
     @pl.when(pg == 0)
     def _init():
@@ -310,91 +354,93 @@ def _prefill_kernel(pt_ref, q_ref, k_ref, v_ref,
     @pl.when(pg * page <= q_hi)                     # causal early-exit
     def _accumulate():
         q = q_ref[0].astype(jnp.float32)            # (bq, D)
-        k = _load_kv_block(kv_format, k_ref, *aux[0::2])   # (page, D)
-        v = _load_kv_block(kv_format, v_ref, *aux[1::2])
-        logits = jnp.dot(q, k.T) / scale            # (bq, page)
+        k = _load_kv_block(kv_format, h, k_ref, *aux[0::2])  # (page, D)
+        v = _load_kv_block(kv_format, h, v_ref, *aux[1::2])
+        logits = jax.lax.dot_general(
+            q, k, _QK_DIMS, preferred_element_type=jnp.float32) / scale
         q_pos = start + qi * bq + jax.lax.broadcasted_iota(
             jnp.int32, (bq, page), 0)
         k_pos = pg * page + jax.lax.broadcasted_iota(
             jnp.int32, (bq, page), 1)
         causal = k_pos <= q_pos
-        logits = jnp.where(causal, logits, _NEG)
-        m_prev = m_sc[:, 0]                         # (bq,)
-        new_m = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-        w = jnp.where(causal, jnp.exp(logits - new_m[:, None]), 0.0)
+        logits = jnp.where(causal, logits, _NEG)    # (bq, page)
+        m_prev = m_sc[...]                          # (bq, 1)
+        new_m = jnp.maximum(m_prev, jnp.max(logits, axis=-1,
+                                            keepdims=True))
+        w = jnp.where(causal, jnp.exp(logits - new_m), 0.0)
         corr = jnp.exp(m_prev - new_m)
-        m_sc[:, 0] = new_m
-        l_sc[:, 0] = l_sc[:, 0] * corr + jnp.sum(w, axis=-1)
-        acc_sc[...] = acc_sc[...] * corr[:, None] + jnp.dot(w, v)
+        m_sc[...] = new_m
+        l_sc[...] = l_sc[...] * corr + jnp.sum(w, axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * corr + jnp.dot(
+            w, v, preferred_element_type=jnp.float32)
 
     @pl.when(pg == n_pg - 1)
     def _finalize():
-        o_ref[0] = (acc_sc[...] /
-                    jnp.maximum(l_sc[:, 0], 1e-30)[:, None]
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
 def paged_attn_prefill_plan(*, G: int, C: int, Hkv: int, Gq: int, D: int,
-                            page: int, start: int, num_pages: int,
-                            table_width: int | None = None,
+                            page: int, num_pages: int, table_width: int,
                             block_q: int = 32, kv_format: str = "fp",
                             q_dtype=jnp.float32,
                             kv_dtype=None) -> LaunchPlan:
     """Static launch geometry of the chunked-prefill kernel (see
-    :func:`paged_attn_decode_plan` for the contract).  ``table_width``
-    is the page-table lane count the engine passes (>= pages seen so
-    far); only lanes ``[0, (start+C)/page)`` are ever indexed."""
+    :func:`paged_attn_decode_plan` for the contract).
+
+    The chunk's first position ``start`` is a scalar-prefetch operand,
+    not a static: one compiled kernel serves every chunk of a prompt,
+    so the engine's chunk loop is a rolled loop.  The page axis spans
+    the whole ``table_width``; pages past a q block's last position are
+    skipped in the body, and their index map repeats the last needed
+    page, so the pipeline never DMAs a page the block cannot see.
+    """
     check_kv_format(kv_format)
     if kv_dtype is None:
         kv_dtype = jnp.float32 if kv_format == "fp" else jnp.int8
-    assert C % page == 0 and start % page == 0, (C, page, start)
+    assert C % page == 0, (C, page)
+    assert table_width * page >= C, (table_width, page, C)
     Hq = Hkv * Gq
-    n_pg = (start + C) // page                      # pages seen so far
-    if table_width is None:
-        table_width = n_pg
-    assert table_width >= n_pg, (table_width, n_pg)
     bq = min(block_q, C)
     if C % bq:
         bq = math.gcd(C, bq)
 
     kernel = functools.partial(_prefill_kernel, bq=bq, page=page,
-                               n_pg=n_pg, start=start,
+                               n_pg=table_width, Hq=Hq, Gq=Gq,
                                scale=math.sqrt(D), kv_format=kv_format)
 
-    def kv_index(bh, qi, pg, pt):
-        return (pt[bh // Hq, pg], 0, (bh % Hq) // Gq, 0)
+    def seen_page(qi, pg, st):
+        # the last page this q block attends; later grid steps re-map
+        # onto it (same block index -> no new DMA)
+        return jnp.minimum(pg, (st[0] + (qi + 1) * bq - 1) // page)
 
-    def scale_index(bh, qi, pg, pt):
-        return (pt[bh // Hq, pg], 0, (bh % Hq) // Gq)
+    def kv_index(bh, qi, pg, pt, st):
+        return (pt[bh // Hq, seen_page(qi, pg, st)], (bh % Hq) // Gq,
+                0, 0)
 
-    kv = dict(shape=(num_pages, page, Hkv, D), dtype=kv_dtype,
-              block=(1, page, 1, D), index_map=kv_index)
-    sc = dict(shape=(num_pages, page, Hkv), dtype=jnp.float32,
-              block=(1, page, 1), index_map=scale_index)
-    inputs = [
-        BlockOperand("q", (G * Hq, C, D), q_dtype, (1, bq, D),
-                     lambda bh, qi, pg, pt: (bh, qi, 0)),
-        BlockOperand("k_pages", **kv),
-        BlockOperand("v_pages", **kv),
-    ]
-    if kv_format != "fp":
-        inputs += [BlockOperand("k_scale", **sc),
-                   BlockOperand("v_scale", **sc)]
-    if kv_format == "sc":
-        inputs += [BlockOperand("k_resid", **kv),
-                   BlockOperand("v_resid", **kv)]
+    def scale_index(bh, qi, pg, pt, st):
+        return (pt[bh // Hq, seen_page(qi, pg, st)], 0, 0)
+
+    inputs = [BlockOperand("q", (G * Hq, C, D), q_dtype, (1, bq, D),
+                           lambda bh, qi, pg, pt, st: (bh, qi, 0))]
+    inputs += _kv_operands(kv_format, num_pages, Hkv, page, D, kv_dtype,
+                           kv_index, scale_index)
 
     return LaunchPlan(
         name="paged_attn_prefill",
-        grid=(G * Hq, C // bq, n_pg),
+        grid=(G * Hq, C // bq, table_width),
         scalars=(
             ScalarOperand("page_tables", (G, table_width), jnp.int32,
                           max_value=num_pages - 1),
+            # the chunk lies inside the table; the index maps are
+            # monotone in start, so 0 and the max bound every chunk
+            ScalarOperand("start", (1,), jnp.int32,
+                          max_value=table_width * page - C),
         ),
         inputs=tuple(inputs),
         outputs=(
             BlockOperand("o", (G * Hq, C, D), q_dtype, (1, bq, D),
-                         lambda bh, qi, pg, pt: (bh, qi, 0)),
+                         lambda bh, qi, pg, pt, st: (bh, qi, 0)),
         ),
         scratch=(((bq, 1), jnp.float32),
                  ((bq, 1), jnp.float32),
@@ -403,17 +449,16 @@ def paged_attn_prefill_plan(*, G: int, C: int, Hkv: int, Gq: int, D: int,
         # every page revisits the same o block; (m,l,acc) live in VMEM
         # scratch and o is written once, under @pl.when(last page) — a
         # single-page launch writes each block exactly once
-        accumulate=({"o": "scratch-finalize"} if n_pg > 1 else {}),
+        accumulate=({"o": "scratch-finalize"} if table_width > 1 else {}),
         single_output=True,
     )
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("start", "block_q", "interpret",
-                                    "kv_format"))
+                   static_argnames=("block_q", "interpret", "kv_format"))
 def paged_attn_prefill_pallas(q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array, page_tables: jax.Array,
-                              *, start: int, block_q: int = 32,
+                              *, start, block_q: int = 32,
                               interpret: bool = False,
                               kv_format: str = "fp",
                               k_scale: jax.Array | None = None,
@@ -423,20 +468,21 @@ def paged_attn_prefill_pallas(q: jax.Array, k_pages: jax.Array,
     """One prefill chunk attending over the paged cache.
 
     q: (G, C, Hkv, Gq, D) — chunk ``[start, start + C)`` of each request
-    in the admission group, C a multiple of the page size and ``start``
-    chunk-aligned (both static); pools: (N, page, Hkv, D), already
-    holding the chunk's whole-page K/V scatter; page_tables: (G, maxp).
-    Compressed pools dequantize in VMEM through the same page-table
-    index maps (see :func:`paged_attn_decode_pallas`).  Returns the
-    context (G, C, Hkv, Gq, D) in q.dtype.  The causal mask matches the
-    reference exactly: ``k_pos <= start + q_row``.
+    in the admission group, C a multiple of the page size (static) and
+    ``start`` a chunk-aligned int32 scalar (traced: every chunk shares
+    one compiled kernel); pools: (N, Hkv, page, D), already holding the
+    chunk's whole-page K/V scatter; page_tables: (G, maxp), covering
+    ``start + C``.  Compressed pools dequantize in VMEM through the same
+    page-table index maps (see :func:`paged_attn_decode_pallas`).
+    Returns the context (G, C, Hkv, Gq, D) in q.dtype.  The causal mask
+    matches the reference exactly: ``k_pos <= start + q_row``.
     """
     check_kv_format(kv_format)
     G, C, Hkv, Gq, D = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     Hq = Hkv * Gq
     plan = paged_attn_prefill_plan(
-        G=G, C=C, Hkv=Hkv, Gq=Gq, D=D, page=page, start=start,
+        G=G, C=C, Hkv=Hkv, Gq=Gq, D=D, page=page,
         num_pages=k_pages.shape[0], table_width=page_tables.shape[1],
         block_q=block_q, kv_format=kv_format, q_dtype=q.dtype,
         kv_dtype=k_pages.dtype)
@@ -445,13 +491,10 @@ def paged_attn_prefill_pallas(q: jax.Array, k_pages: jax.Array,
     # request bh // Hq; its KV head is (bh % Hq) // Gq (GQA grouping as
     # in flash_attention's kv index map)
     qh = jnp.moveaxis(q.reshape(G, C, Hq, D), 2, 1).reshape(G * Hq, C, D)
+    st = jnp.asarray(start, jnp.int32).reshape(1)
 
-    aux_ops = []
-    if kv_format != "fp":
-        aux_ops += [k_scale, v_scale]
-    if kv_format == "sc":
-        aux_ops += [k_resid, v_resid]
-    out = call_plan(plan, (page_tables, qh, k_pages, v_pages, *aux_ops),
-                    interpret=interpret)
+    aux_ops = _aux_operands(kv_format, k_scale, v_scale, k_resid, v_resid)
+    out = call_plan(plan, (page_tables, st, qh, k_pages, v_pages,
+                           *aux_ops), interpret=interpret)
     out = jnp.moveaxis(out.reshape(G, Hq, C, D), 1, 2)
     return out.reshape(G, C, Hkv, Gq, D)

@@ -115,11 +115,8 @@ def estimate_vmem(plan: LaunchPlan) -> int:
 
 
 def compiler_params(semantics: tuple[str, ...]):
-    """dimension_semantics across the jax naming change."""
-    try:
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except AttributeError:                           # older jax naming
-        return pltpu.TPUCompilerParams(dimension_semantics=semantics)
+    """Mosaic compiler params carrying the grid's dimension_semantics."""
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def call_plan(plan: LaunchPlan, operands: tuple, *,

@@ -52,16 +52,31 @@ def bsn_sort_ref(bits: jax.Array) -> jax.Array:
     return jnp.sort(bits, axis=-1)[..., ::-1]
 
 
-def gather_pages(pages: jax.Array, page_tables: jax.Array) -> jax.Array:
-    """(N, page, ...) pool + (S, maxp) tables -> (S, maxp*page, ...).
+def to_pages(x: jax.Array, page: int, axis: int = 1) -> jax.Array:
+    """Position-major -> head-major pages, the KV pools' layout:
+    ``(..., n*page, Hkv, ...)`` with the positions at ``axis`` ->
+    ``(..., n, Hkv, page, ...)``.  :func:`from_pages` inverts it."""
+    n = x.shape[axis] // page
+    x = x.reshape(*x.shape[:axis], n, page, *x.shape[axis + 1:])
+    return jnp.swapaxes(x, axis + 1, axis + 2)
 
-    Works for KV pools (N, page, H, Dh) and their parallel scale pools
-    (N, page, H) alike — the trailing axes ride along unchanged.
+
+def from_pages(x: jax.Array, axis: int = 1) -> jax.Array:
+    """Head-major pages -> position-major: ``(..., n, Hkv, page, ...)``
+    with the pages at ``axis`` -> ``(..., n*page, Hkv, ...)``."""
+    x = jnp.swapaxes(x, axis + 1, axis + 2)
+    return x.reshape(*x.shape[:axis], -1, *x.shape[axis + 2:])
+
+
+def gather_pages(pages: jax.Array, page_tables: jax.Array) -> jax.Array:
+    """Head-major (N, Hkv, page, ...) pool + (S, maxp) tables ->
+    position-major (S, maxp*page, Hkv, ...).
+
+    Works for KV pools (N, Hkv, page, Dh) and their parallel scale pools
+    (N, Hkv, page) alike — the trailing axes ride along unchanged.
     """
-    S, maxp = page_tables.shape
-    page = pages.shape[1]
-    g = jnp.take(pages, page_tables.reshape(-1), axis=0)
-    return g.reshape(S, maxp * page, *pages.shape[2:])
+    g = jnp.take(pages, page_tables, axis=0)        # (S, maxp, Hkv, page, …)
+    return from_pages(g)
 
 
 def gather_pages_dequant(pages: jax.Array, page_tables: jax.Array, *,
@@ -89,7 +104,7 @@ def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,
                           kv_aux: dict | None = None) -> jax.Array:
     """XLA gather/scatter paged decode — the paged-kernel ground truth.
 
-    q: (S, Hkv, G, D); pools: (N, page, Hkv, D) already holding the new
+    q: (S, Hkv, G, D); pools: (N, Hkv, page, D) already holding the new
     token at position ``lengths``; page_tables: (S, maxp) int32;
     lengths: (S,) int32.  Gathers each slot's full ``maxp*page`` KV
     window, masks positions past ``lengths`` and softmaxes — positions
@@ -98,7 +113,7 @@ def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,
     is a hook for the mesh path's sharding constraint (models/attention
     pins the KV-head axis to "model" there).  For compressed pools
     (``kv_format`` "int8"/"sc"), ``kv_aux`` carries the parallel
-    ``k_scale``/``v_scale`` (N, page, Hkv) and — for sc — the
+    ``k_scale``/``v_scale`` (N, Hkv, page) and — for sc — the
     ``k_resid``/``v_resid`` pools; dequant is fused into the gather.
     Returns (S, Hkv, G, D) in q.dtype.
     """
@@ -162,26 +177,27 @@ def paged_attn_verify_ref(q: jax.Array, k_pages: jax.Array,
 
 def paged_attn_prefill_ref(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_tables: jax.Array,
-                           start: int, *, pin_logits=None,
+                           start, *, pin_logits=None,
                            kv_format: str = "fp",
                            kv_aux: dict | None = None) -> jax.Array:
     """XLA gather paged prefill — chunk ``[start, start+C)`` attends over
     every page written so far under the causal mask.
 
-    q: (G, C, Hkv, Gq, D); pools: (N, page, Hkv, D) already holding the
-    chunk's whole-page K/V scatter; page_tables: (G, maxp).  Compressed
-    pools dequantize inside the gather via ``kv_aux`` exactly as in
-    :func:`paged_attn_decode_ref`.  Returns (G, C, Hkv, Gq, D) in
+    q: (G, C, Hkv, Gq, D); pools: (N, Hkv, page, D) already holding the
+    chunk's whole-page K/V scatter; page_tables: (G, maxp).  ``start``
+    may be traced (the engine's chunk loop is rolled): the whole table
+    window is gathered and positions past ``start + q_row`` are masked,
+    which also hides every page the prefill has not written yet.
+    Compressed pools dequantize inside the gather via ``kv_aux`` exactly
+    as in :func:`paged_attn_decode_ref`.  Returns (G, C, Hkv, Gq, D) in
     q.dtype.
     """
     G, C, Hkv, Gq, D = q.shape
-    page = k_pages.shape[1]
-    seen = page_tables[:, :(start + C) // page]   # pages <= this chunk
     aux = kv_aux or {}
-    kg = gather_pages_dequant(k_pages, seen, kv_format=kv_format,
+    kg = gather_pages_dequant(k_pages, page_tables, kv_format=kv_format,
                               scale=aux.get("k_scale"),
                               resid=aux.get("k_resid"))  # (G, T, Hkv, Dh)
-    vg = gather_pages_dequant(v_pages, seen, kv_format=kv_format,
+    vg = gather_pages_dequant(v_pages, page_tables, kv_format=kv_format,
                               scale=aux.get("v_scale"),
                               resid=aux.get("v_resid"))
     T = kg.shape[1]

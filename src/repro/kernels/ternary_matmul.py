@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .plan import compiler_params
+
 __all__ = ["ternary_matmul_pallas"]
 
 
@@ -96,19 +98,13 @@ def ternary_matmul_pallas(x_q: jax.Array, w_int: jax.Array,
         (m, n, k), (block_m, block_n, block_k))
     grid = (m // block_m, n // block_n, k // block_k)
 
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except AttributeError:  # older pallas naming
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     common = dict(
         grid=grid,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=compiler_params,
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )
     x_spec = pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk))

@@ -1,5 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU dry run: pin this process and the --all children (which inherit
+# the environment) to the CPU, so no launcher ever takes a chip, and
+# add the forced host device count to any XLA_FLAGS already set
+os.environ["JAX_PLATFORMS"] = "cpu"
+_FORCE = "--xla_force_host_platform_device_count=512"
+if _FORCE not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               + _FORCE).strip()
 
 # --- everything below may import jax -------------------------------------
 """Multi-pod dry-run (deliverable e): lower + compile every

@@ -243,10 +243,12 @@ def attn_decode(p: dict, x: jax.Array, cfg: ModelConfig,
 # ---------------------------------------------------------------------------
 #
 # The serving engine stores KV in a flat pool of fixed-size pages shared
-# by every request (serving/paging.py owns the allocation); the two
+# by every request (serving/paging.py owns the allocation); the
 # functions below scatter the new K/V into the pools and attend over
-# that layout.  Per slot ``s`` position ``t`` lives at physical page
-# ``page_tables[s, t // page]`` offset ``t % page``.  Page-table padding
+# that layout.  Pools are head-major, ``(N, Hkv, page, Dh)`` (scales
+# ``(N, Hkv, page)``): per slot ``s`` position ``t`` of KV head ``h``
+# lives at ``pool[page_tables[s, t // page], h, t % page]``.  Page-table
+# padding
 # points at the reserved trash page (writes land there harmlessly; reads
 # are masked by ``lengths``), so no cross-request leakage is possible by
 # construction.
@@ -274,8 +276,8 @@ _AUX_KEYS = ("k_scale", "v_scale", "k_resid", "v_resid")
 def _pin_pool(a: jax.Array) -> jax.Array:
     """Pools stay KV-head-sharded across steps (weights-resident layout);
     scatter indices are replicated, so the update is device-local.  Works
-    for KV/resid pools (N, page, Hkv, Dh) and scale pools (N, page, Hkv)."""
-    return constrain(a, None, None, "model", *(None,) * (a.ndim - 3))
+    for KV/resid pools (N, Hkv, page, Dh) and scale pools (N, Hkv, page)."""
+    return constrain(a, None, "model", *(None,) * (a.ndim - 2))
 
 
 def _scatter_pools(pools: dict, fmt: str, k_new: jax.Array,
@@ -306,13 +308,13 @@ def attn_decode_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     """Batched one-token decode over the paged KV cache.
 
     x: (S, 1, D) — one new token per active slot; ``pools`` holds the
-    (N, page, Hkv, Dh) KV pools + (S, maxp) int32 ``page_tables`` (+ any
+    (N, Hkv, page, Dh) KV pools + (S, maxp) int32 ``page_tables`` (+ any
     scale/resid leaves); lengths: (S,) int32 tokens already in the cache
     (== the new token's position).  Returns (y (S, 1, D), new_pools) —
     the updated pool leaves, page_tables excluded.
     """
     page_tables = pools["page_tables"]
-    page = pools["k_pages"].shape[1]
+    page = pools["k_pages"].shape[2]
     fmt = kv_format_of(pools)
     S = x.shape[0]
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -327,7 +329,8 @@ def attn_decode_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     off = lengths % page
     new_pools = _scatter_pools(
         pools, fmt, k[:, 0], v[:, 0],
-        lambda pool, val: pool.at[phys, off].set(val.astype(pool.dtype)))
+        lambda pool, val: pool.at[phys, :, off].set(
+            val.astype(pool.dtype)))
 
     qg = q.reshape(S, hkv, g, dh)
     aux = _kv_aux(new_pools)
@@ -371,7 +374,7 @@ def attn_verify_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     path's logit pin matching decode.
     """
     page_tables = pools["page_tables"]
-    page = pools["k_pages"].shape[1]
+    page = pools["k_pages"].shape[2]
     fmt = kv_format_of(pools)
     S, T = x.shape[0], x.shape[1]
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -382,7 +385,8 @@ def attn_verify_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     off = positions % page                                  # (S, T)
     new_pools = _scatter_pools(
         pools, fmt, k, v,
-        lambda pool, val: pool.at[phys, off].set(val.astype(pool.dtype)))
+        lambda pool, val: pool.at[phys, :, off].set(
+            val.astype(pool.dtype)))
 
     qg = q.reshape(S, T, hkv, g, dh)
     aux = _kv_aux(new_pools)
@@ -403,25 +407,26 @@ def attn_verify_paged(p: dict, x: jax.Array, cfg: ModelConfig,
 
 
 def attn_prefill_paged(p: dict, x: jax.Array, cfg: ModelConfig,
-                       pools: dict, start: int):
+                       pools: dict, start):
     """One prefill chunk written straight into the decode page layout.
 
     x: (G, C, D) — chunk ``[start, start+C)`` of each request in the
-    admission group, with ``C`` a multiple of the page size and ``start``
-    chunk-aligned (static).  K/V of the chunk are scattered as whole
-    pages (quantized on scatter for compressed ``pools``), then the
-    chunk's queries attend over every page written so far (positions
-    < start + C) under the causal mask — the online equivalent of flash
-    prefill, sharing the decode cache layout so no re-layout pass sits
-    between prefill and decode.
+    admission group, with ``C`` a multiple of the page size (static) and
+    ``start`` a chunk-aligned int32 scalar (traced: the chunk loop is
+    rolled, one compiled body for every chunk).  K/V of the chunk are
+    scattered as whole pages (quantized on scatter for compressed
+    ``pools``), then the chunk's queries attend over every page written
+    so far (positions < start + C) under the causal mask — the online
+    equivalent of flash prefill, sharing the decode cache layout so no
+    re-layout pass sits between prefill and decode.
 
     Returns (y (G, C, D), new_pools).
     """
     page_tables = pools["page_tables"]
-    page = pools["k_pages"].shape[1]
+    page = pools["k_pages"].shape[2]
     fmt = kv_format_of(pools)
     G, C, _ = x.shape
-    assert C % page == 0 and start % page == 0, (C, page, start)
+    assert C % page == 0, (C, page)
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     g = hq // hkv
     positions = start + jnp.broadcast_to(
@@ -429,13 +434,17 @@ def attn_prefill_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     q, k, v = _project_qkv(p, x, cfg, positions)            # (G,C,H,Dh)
 
     # whole-page scatter: chunk pages j cover positions start + j*page
-    p0 = start // page
     npg = C // page
-    phys = page_tables[:, p0:p0 + npg].reshape(-1)          # (G*npg,)
-    new_pools = _scatter_pools(
-        pools, fmt, k, v,
-        lambda pool, val: pool.at[phys].set(
-            val.reshape(G * npg, page, *val.shape[2:]).astype(pool.dtype)))
+    phys = jax.lax.dynamic_slice_in_dim(
+        page_tables, start // page, npg, axis=1).reshape(-1)  # (G*npg,)
+
+    def put(pool, val):
+        # (G, C, Hkv, ...) -> (G*npg, Hkv, page, ...) whole pages
+        val = kernel_ref.to_pages(val, page)
+        val = val.reshape(G * npg, *val.shape[2:])
+        return pool.at[phys].set(val.astype(pool.dtype))
+
+    new_pools = _scatter_pools(pools, fmt, k, v, put)
 
     qg = q.reshape(G, C, hkv, g, dh)
     aux = _kv_aux(new_pools)

@@ -122,6 +122,27 @@ def norm_spec(kind: str) -> dict:
     return s
 
 
+def _row_mean(x: jax.Array) -> jax.Array:
+    """Mean over the last axis (kept), summed as a fixed pairwise tree of
+    elementwise adds.
+
+    XLA lowers a reduce in an order that follows the array's shape and
+    layout — on a TPU a (1, 1, d) row and a (4, 64, d) batch are summed
+    differently — so a row's norm statistic would change with its batch
+    neighbours.  Halving adds fix the order per row, which keeps batched
+    serving bit-identical to the one-request oracle.
+    """
+    n = x.shape[-1]
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        y = x[..., :h] + x[..., h:2 * h]
+        if x.shape[-1] % 2:
+            y = jnp.concatenate([y[..., :1] + x[..., 2 * h:], y[..., 1:]],
+                                axis=-1)
+        x = y
+    return x / n
+
+
 def norm_apply(p: dict, x: jax.Array, kind: str, eps: float = 1e-6,
                groups: int = 0) -> jax.Array:
     """rmsnorm / layernorm / (grouped layernorm when groups > 0, for RWKV)."""
@@ -130,17 +151,17 @@ def norm_apply(p: dict, x: jax.Array, kind: str, eps: float = 1e-6,
     if groups:
         shp = xf.shape[:-1] + (groups, xf.shape[-1] // groups)
         xg = xf.reshape(shp)
-        mu = xg.mean(-1, keepdims=True)
-        var = ((xg - mu) ** 2).mean(-1, keepdims=True)
+        mu = _row_mean(xg)
+        var = _row_mean((xg - mu) ** 2)
         xf = ((xg - mu) * jax.lax.rsqrt(var + eps)).reshape(xf.shape)
         out = xf * p["scale"] + p.get("bias", 0.0)
         return out.astype(dt)
     if kind == "rmsnorm":
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        var = _row_mean(xf * xf)
         out = xf * jax.lax.rsqrt(var + eps) * p["scale"]
     elif kind == "layernorm":
-        mu = xf.mean(-1, keepdims=True)
-        var = ((xf - mu) ** 2).mean(-1, keepdims=True)
+        mu = _row_mean(xf)
+        var = _row_mean((xf - mu) ** 2)
         out = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
     else:
         raise ValueError(kind)

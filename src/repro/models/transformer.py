@@ -518,8 +518,8 @@ def prefill(params: dict, batch: dict, cfg: ModelConfig):
 # paged serving cache (ServeEngine v2)
 # ---------------------------------------------------------------------------
 #
-# Layout: attention positions hold *shared* page pools
-# ``(num_pages, page, Hkv, Dh)`` (which request owns which page is the
+# Layout: attention positions hold *shared* head-major page pools
+# ``(num_pages, Hkv, page, Dh)`` (which request owns which page is the
 # engine's page table, serving/paging.py); recurrent positions hold
 # per-slot state ROWS ``(max_slots + 1, ...)`` — row ``max_slots`` is the
 # scratch lane that padded lanes read/write so bucket padding never
@@ -547,8 +547,12 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, num_pages: int,
     """``kv_format`` (core/kv_quant.py) picks the attention pool storage:
     "fp" keeps cfg.dtype pages; "int8"/"sc" store int8 level pools plus a
     parallel per-position-per-head f32 scale pool (+ the sc int8 residual
-    pool).  All-zero init dequantizes to exact 0 in every format, so the
-    trash page and unwritten positions behave identically to fp."""
+    pool).  Pools are head-major — KV ``(num_pages, Hkv, page, Dh)``,
+    scales ``(num_pages, Hkv, page)`` — so a Pallas block of one head's
+    page ``(page, Dh)`` spans whole trailing dims (kernels/
+    paged_attention.py).  All-zero init dequantizes to exact 0 in every
+    format, so the trash page and unwritten positions behave identically
+    to fp."""
     check_kv_format(kv_format)
     dtype = jnp.dtype(cfg.dtype)
     rows = max_slots + 1                      # + scratch lane
@@ -558,14 +562,14 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, num_pages: int,
         e = {}
         if spec.mixer == "attn":
             kv_dt = dtype if kv_format == "fp" else jnp.int8
-            e["k_pages"] = jnp.zeros((num_pages, page_size, hkv, dh), kv_dt)
-            e["v_pages"] = jnp.zeros((num_pages, page_size, hkv, dh), kv_dt)
+            e["k_pages"] = jnp.zeros((num_pages, hkv, page_size, dh), kv_dt)
+            e["v_pages"] = jnp.zeros((num_pages, hkv, page_size, dh), kv_dt)
             if kv_format != "fp":
-                sshape = (num_pages, page_size, hkv)
+                sshape = (num_pages, hkv, page_size)
                 e["k_scale"] = jnp.zeros(sshape, jnp.float32)
                 e["v_scale"] = jnp.zeros(sshape, jnp.float32)
             if kv_format == "sc":
-                rshape = (num_pages, page_size, hkv, dh)
+                rshape = (num_pages, hkv, page_size, dh)
                 e["k_resid"] = jnp.zeros(rshape, jnp.int8)
                 e["v_resid"] = jnp.zeros(rshape, jnp.int8)
         elif spec.mixer == "mamba":
@@ -600,16 +604,16 @@ def paged_cache_specs(cfg: ModelConfig, kv_format: str = "fp") -> dict:
     def entry(spec: LayerSpec) -> dict:
         e = {}
         if spec.mixer == "attn":
-            # (n_periods, num_pages, page, Hkv, Dh)
-            e["k_pages"] = (None, None, None, "model", None)
-            e["v_pages"] = (None, None, None, "model", None)
+            # (n_periods, num_pages, Hkv, page, Dh)
+            e["k_pages"] = (None, None, "model", None, None)
+            e["v_pages"] = (None, None, "model", None, None)
             if kv_format != "fp":
-                # (n_periods, num_pages, page, Hkv)
-                e["k_scale"] = (None, None, None, "model")
-                e["v_scale"] = (None, None, None, "model")
+                # (n_periods, num_pages, Hkv, page)
+                e["k_scale"] = (None, None, "model", None)
+                e["v_scale"] = (None, None, "model", None)
             if kv_format == "sc":
-                e["k_resid"] = (None, None, None, "model", None)
-                e["v_resid"] = (None, None, None, "model", None)
+                e["k_resid"] = (None, None, "model", None, None)
+                e["v_resid"] = (None, None, "model", None, None)
         elif spec.mixer == "mamba":
             # h: (n_periods, rows, d_inner, n); conv: (…, k-1, d_inner)
             e["h"] = (None, None, "model", None)
@@ -843,9 +847,9 @@ def paged_prefill(params: dict, cache: dict, tokens: jax.Array,
     assert L % chunk == 0, (L, chunk)
     table = params["embed"]["table"]
     h_last = jnp.zeros((G, cfg.d_model), table.dtype)
-    # split the cache: shared page pools ride the chunk loop; per-slot
-    # state rows are untouched until the final scatter (prompt state
-    # starts from zero, never from a previous occupant's rows)
+    # split the cache: shared page pools ride the chunk loop's carry;
+    # per-slot state rows are untouched until the final scatter (prompt
+    # state starts from zero, never from a previous occupant's rows)
     pools, rows = {}, {}
     for i in range(len(cfg.period)):
         pe = cache["periods"][f"p{i}"]
@@ -861,39 +865,57 @@ def paged_prefill(params: dict, cache: dict, tokens: jax.Array,
     gstate = jax.tree.map(
         lambda a: jnp.broadcast_to(a, (cfg.n_periods,) + a.shape), one)
 
-    for c in range(L // chunk):
+    layer_ids = jnp.arange(cfg.n_periods, dtype=jnp.int32)
+
+    def chunk_body(carry, c):
+        # one chunk = the full period scan.  The chunk loop is rolled
+        # (``start`` is traced): one compiled body serves every chunk,
+        # and the pools are a loop CARRY updated one layer at a time
+        # (dynamic index in, ``.at[layer].set`` out), so XLA updates
+        # them in place — prefill memory does not grow with the number
+        # of chunks.
+        pools, gstate, h_last = carry
         start = c * chunk
-        xc = jnp.take(table, tokens[:, start:start + chunk], axis=0)
+        xc = jnp.take(table, jax.lax.dynamic_slice_in_dim(
+            tokens, start, chunk, axis=1), axis=0)
         xc = constrain(xc, None, None, None)
         valid = (start + jnp.arange(chunk, dtype=jnp.int32))[None, :] \
             < prompt_lens[:, None]                        # (G, chunk)
 
-        def period_body(x, inp, start=start, valid=valid):
-            pp, cper, gsper = inp
-            new_pools, new_gs = {}, {}
+        def period_body(carry, inp):
+            x, pools = carry
+            pp, gsper, li = inp
+            pools, new_gs = dict(pools), {}
             for idx, spec in enumerate(cfg.period):
-                cst = dict(cper[f"p{idx}"])
-                cst.update(gsper[f"p{idx}"])
+                key = f"p{idx}"
+                cst = {k: v[li] for k, v in pools[key].items()}
+                cst.update(gsper[key])
                 cst["page_tables"] = page_tables
                 cst["start"] = start
                 cst["valid"] = valid
-                x, _, ce = _apply_position(pp[f"p{idx}"], spec, x, cfg,
+                x, _, ce = _apply_position(pp[key], spec, x, cfg,
                                            None, "paged_prefill", cst, None)
-                new_pools[f"p{idx}"] = {k: v for k, v in ce.items()
-                                        if k in _POOL_KEYS}
-                new_gs[f"p{idx}"] = constrain_tree(
+                pools[key] = {k: v.at[li].set(ce[k])
+                              for k, v in pools[key].items()}
+                new_gs[key] = constrain_tree(
                     {k: v for k, v in ce.items() if k not in _POOL_KEYS},
                     _group_state_specs(cfg, idx))
-            return x, (new_pools, new_gs)
+            return (x, pools), new_gs
 
-        xc, (pools, gstate) = jax.lax.scan(
-            period_body, xc, (params["periods"], pools, gstate))
+        (xc, pools), gstate = jax.lax.scan(
+            period_body, (xc, pools),
+            (params["periods"], gstate, layer_ids))
         # keep the hidden state of each request's last real token
         last = prompt_lens - 1 - start
         rws = jnp.take_along_axis(
             xc, jnp.clip(last, 0, chunk - 1)[:, None, None], axis=1)[:, 0]
         h_last = jnp.where(((last >= 0) & (last < chunk))[:, None],
                            rws, h_last)
+        return (pools, gstate, h_last), None
+
+    (pools, gstate, h_last), _ = jax.lax.scan(
+        chunk_body, (pools, gstate, h_last),
+        jnp.arange(L // chunk, dtype=jnp.int32))
 
     # scatter each lane's final carry into its slot's state rows (padded
     # lanes land in the scratch row, whose contents no live request

@@ -96,6 +96,7 @@ from repro.configs.base import ModelConfig
 from repro.core.kv_quant import kv_quant
 from repro.distributed.sharding import MeshRules, mesh_rules, shard_tree
 from repro.kernels import dispatch as kernel_dispatch
+from repro.kernels.ref import to_pages
 from repro.models import (decode_step, gather_state_rows, init_paged_cache,
                           paged_cache_specs, paged_decode_step,
                           paged_prefill, paged_verify_step, param_specs,
@@ -110,6 +111,14 @@ from .sampling import (SamplingParams, greedy_tokens, pack_sampling,
 
 __all__ = ["Request", "SamplingParams", "ServeEngine", "EngineConfig",
            "DATAPATHS", "sequential_generate"]
+
+# Every program of the engine and of its oracle rounds bf16 where the
+# program says.  By default XLA may keep a fused bf16 intermediate in
+# f32 (TPU fusions do), so a row's result would follow the fusion
+# choices of the program it runs in, and a batched step would stop
+# matching the one-request oracle.
+STRICT_ROUNDING = {"xla_allow_excess_precision": False}
+_jit = partial(jax.jit, compiler_options=STRICT_ROUNDING)
 
 
 def _cfg_for_datapath(cfg: ModelConfig, datapath: str) -> ModelConfig:
@@ -242,28 +251,28 @@ class ServeEngine:
                 "out_shardings": (rep, self._cache_sh)}
             spec_jit_kw["verify"] = {
                 "out_shardings": (rep, rep, self._cache_sh, rep)}
-        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,),
-                               static_argnames=("do_sample", "lp_k"),
-                               **jit_kw)
-        self._prefill_batched = jax.jit(self._prefill_batched_fn,
-                                        static_argnames=("chunk",
-                                                         "do_sample",
-                                                         "lp_k"),
-                                        donate_argnums=(1,), **jit_kw)
+        self._decode = _jit(self._decode_fn, donate_argnums=(1,),
+                            static_argnames=("do_sample", "lp_k"),
+                            **jit_kw)
+        self._prefill_batched = _jit(self._prefill_batched_fn,
+                                     static_argnames=("chunk",
+                                                      "do_sample",
+                                                      "lp_k"),
+                                     donate_argnums=(1,), **jit_kw)
         # The exact-prefill debug oracle is donation-EXEMPT by design
         # (analysis/contracts.audit_donation records the exemption): it
         # takes (params, batch) only and builds a fresh exact-length
         # cache, so there is no input cache buffer to alias an output
         # into — donating nothing is correct, not an oversight.
-        self._prefill_exact = jax.jit(self._prefill_exact_fn,
-                                      static_argnames=("do_sample",
-                                                       "lp_k"))
-        self._draft = jax.jit(self._draft_fn, donate_argnums=(1,),
-                              static_argnames=("do_sample",),
-                              **spec_jit_kw.get("draft", {}))
-        self._verify = jax.jit(self._verify_fn, donate_argnums=(1,),
-                               static_argnames=("do_sample", "lp_k"),
-                               **spec_jit_kw.get("verify", {}))
+        self._prefill_exact = _jit(self._prefill_exact_fn,
+                                   static_argnames=("do_sample",
+                                                    "lp_k"))
+        self._draft = _jit(self._draft_fn, donate_argnums=(1,),
+                           static_argnames=("do_sample",),
+                           **spec_jit_kw.get("draft", {}))
+        self._verify = _jit(self._verify_fn, donate_argnums=(1,),
+                            static_argnames=("do_sample", "lp_k"),
+                            **spec_jit_kw.get("verify", {}))
 
     @classmethod
     def from_config(cls, params, cfg: ModelConfig,
@@ -585,9 +594,7 @@ class ServeEngine:
                     for pool_name, sv in stores.items():
                         pads = [(0, 0)] * sv.ndim
                         pads[1] = (0, npg * page - plen)
-                        sv = jnp.pad(sv, pads)
-                        sv = sv.reshape(sv.shape[0], npg, page,
-                                        *sv.shape[2:])
+                        sv = to_pages(jnp.pad(sv, pads), page)
                         pool = entry[pool_name]
                         entry[pool_name] = pool.at[:, phys].set(
                             sv.astype(pool.dtype))
@@ -877,11 +884,11 @@ def sequential_generate(params, cfg: ModelConfig, prompts: list[list[int]],
     # XLA, and on the fake-quant lattice that 1-ulp drift can flip exact
     # argmax ties — the differential theorem needs both sides compiled
     # under the same discipline.
-    prefill_fn = jax.jit(lambda p, b: prefill(p, b, cfg))
-    decode_fn = jax.jit(lambda p, c, t: decode_step(p, c, t, cfg))
-    sample_fn = jax.jit(
+    prefill_fn = _jit(lambda p, b: prefill(p, b, cfg))
+    decode_fn = _jit(lambda p, c, t: decode_step(p, c, t, cfg))
+    sample_fn = _jit(
         lambda lg, pos, sm: sample_tokens(lg, pos, sm, cfg.vocab_size))
-    greedy_fn = jax.jit(lambda lg: greedy_tokens(lg, cfg.vocab_size))
+    greedy_fn = _jit(lambda lg: greedy_tokens(lg, cfg.vocab_size))
     outs = []
     with kernel_dispatch.backend_scope(bsn_backend):
         for prompt, sp in zip(prompts, sps):
@@ -911,7 +918,7 @@ def sequential_generate(params, cfg: ModelConfig, prompts: list[list[int]],
     return outs
 
 
-@partial(jax.jit, static_argnames=("cfg", "chunk", "bsn_backend"))
+@partial(_jit, static_argnames=("cfg", "chunk", "bsn_backend"))
 def _oracle_paged_prefill(params, cache, tokens, tables, plen, slot_ids,
                           *, cfg: ModelConfig, chunk: int,
                           bsn_backend: str | None):
@@ -928,7 +935,7 @@ def _oracle_paged_prefill(params, cache, tokens, tables, plen, slot_ids,
                              chunk=chunk, slot_ids=slot_ids)
 
 
-@partial(jax.jit, static_argnames=("cfg", "bsn_backend"))
+@partial(_jit, static_argnames=("cfg", "bsn_backend"))
 def _oracle_paged_decode(params, cache, tok, slot_ids, tables, lengths,
                          *, cfg: ModelConfig, bsn_backend: str | None):
     """Module-level jit for the paged oracle's decode step (same caching
@@ -952,9 +959,9 @@ def _paged_sequential_generate(params, cfg: ModelConfig, prompts, sps,
     dense oracle, on the compressed pool layout."""
     assert supports_paged_prefill(cfg), \
         "compressed-KV sequential oracle needs token prompts"
-    sample_fn = jax.jit(
+    sample_fn = _jit(
         lambda lg, pos, sm: sample_tokens(lg, pos, sm, cfg.vocab_size))
-    greedy_fn = jax.jit(lambda lg: greedy_tokens(lg, cfg.vocab_size))
+    greedy_fn = _jit(lambda lg: greedy_tokens(lg, cfg.vocab_size))
     slot_ids = jnp.zeros((1,), jnp.int32)
     outs = []
     with kernel_dispatch.backend_scope(bsn_backend):

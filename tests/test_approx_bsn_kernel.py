@@ -45,7 +45,8 @@ def _random_spec(rng: np.random.Generator) -> ApproxBSNSpec:
     bsl, stages = in_bsl, []
     for g in groups:
         sorted_len = bsl * g
-        stride = int(2 ** rng.integers(0, 3))         # 1, 2, 4
+        # 1, 2, 4, never past the sorted length (a stage keeps >= 1 bit)
+        stride = min(int(2 ** rng.integers(0, 3)), sorted_len)
         max_out = sorted_len // stride
         out_bsl = int(rng.integers(1, max_out + 1))
         if (sorted_len - out_bsl * stride) % 2:       # clip must be symmetric

@@ -138,8 +138,8 @@ def _pools(seed, S, Hkv, D, page, maxp, fmt):
     """Quantized pools + tables, allocator-style (page 0 = trash)."""
     rng = np.random.default_rng(seed)
     n = S * maxp + 1
-    kf = jnp.asarray(rng.standard_normal((n, page, Hkv, D)), jnp.float32)
-    vf = jnp.asarray(rng.standard_normal((n, page, Hkv, D)), jnp.float32)
+    kf = jnp.asarray(rng.standard_normal((n, Hkv, page, D)), jnp.float32)
+    vf = jnp.asarray(rng.standard_normal((n, Hkv, page, D)), jnp.float32)
     kq, vq = kv_quant(kf, fmt), kv_quant(vf, fmt)
     aux = {}
     if fmt != "fp":
@@ -262,9 +262,9 @@ def test_attention_output_within_lipschitz_bound(fmt):
     S, Hkv, G, D, page, maxp = 2, 2, 2, 16, 8, 3
     rng = np.random.default_rng(31)
     n = S * maxp + 1
-    kf = jnp.asarray(rng.standard_normal((n, page, Hkv, D)) * 0.5,
+    kf = jnp.asarray(rng.standard_normal((n, Hkv, page, D)) * 0.5,
                      jnp.float32)
-    vf = jnp.asarray(rng.standard_normal((n, page, Hkv, D)) * 0.5,
+    vf = jnp.asarray(rng.standard_normal((n, Hkv, page, D)) * 0.5,
                      jnp.float32)
     tables = np.zeros((S, maxp), np.int32)
     for s in range(S):

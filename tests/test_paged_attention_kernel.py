@@ -39,8 +39,8 @@ def _paged_case(seed, S, Hkv, D, page, maxp):
     page 0 reserved (trash), distinct physical pages per slot."""
     rng = np.random.default_rng(seed)
     n = S * maxp + 1
-    kp = jnp.asarray(rng.standard_normal((n, page, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n, page, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((n, Hkv, page, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((n, Hkv, page, D)), jnp.float32)
     tables = np.zeros((S, maxp), np.int32)
     for s in range(S):
         tables[s] = 1 + s * maxp + rng.permutation(maxp)

@@ -30,6 +30,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import LayerSpec, get_arch
+from repro.kernels.ref import from_pages, to_pages
 from repro.models import init_params
 from repro.serving import (PageAllocator, PageTable, SamplingParams,
                            ServeEngine, kv_page_bytes, sequential_generate)
@@ -446,12 +447,15 @@ def _poison_pools(eng, keep):
         for name in ("k_pages", "v_pages", "k_scale", "v_scale",
                      "k_resid", "v_resid"):
             if name in entry:
-                pool = np.asarray(entry[name]).copy()
-                mask = np.ones(pool.shape[1:3], bool)   # (num_pages, page)
+                # head-major (n_periods, num_pages, Hkv, page, ...): view
+                # it position-major to mask (num_pages, page) pairs
+                page = entry[name].shape[3]
+                pool = np.array(from_pages(entry[name]))
+                mask = np.ones(pool.shape[1], bool)     # num_pages * page
                 for pg, off in keep:
-                    mask[pg, off] = False
+                    mask[pg * page + off] = False
                 pool[:, mask] = 127 if pool.dtype == np.int8 else 3e4
-                entry[name] = jnp.asarray(pool)
+                entry[name] = to_pages(jnp.asarray(pool), page)
         periods[key] = entry
     eng.cache = {"periods": periods}
 

@@ -141,12 +141,12 @@ def test_kv_scale_and_residual_pools_shard_with_the_code_pages():
                       page_size=8, mesh_rules=_rules(), datapath="sc_int",
                       kv_format="sc")
     entry = eng.cache["periods"]["p0"]
-    # codes / residuals: (n_periods, num_pages, page, Hkv, Dh)
-    assert entry["k_pages"].sharding.spec[3] == "model"
-    assert entry["k_resid"].sharding.spec[3] == "model"
-    # scales: (n_periods, num_pages, page, Hkv)
-    assert entry["k_scale"].sharding.spec[3] == "model"
-    assert entry["v_scale"].sharding.spec[3] == "model"
+    # codes / residuals: (n_periods, num_pages, Hkv, page, Dh)
+    assert entry["k_pages"].sharding.spec[2] == "model"
+    assert entry["k_resid"].sharding.spec[2] == "model"
+    # scales: (n_periods, num_pages, Hkv, page)
+    assert entry["k_scale"].sharding.spec[2] == "model"
+    assert entry["v_scale"].sharding.spec[2] == "model"
 
 
 def test_kv_pools_sharded_over_model_axis():
@@ -156,8 +156,8 @@ def test_kv_pools_sharded_over_model_axis():
     eng = ServeEngine(params, ATTN_CFG, max_slots=2, max_len=32,
                       page_size=8, mesh_rules=_rules())
     kp = eng.cache["periods"]["p0"]["k_pages"]
-    # (n_periods, num_pages, page, Hkv, Dh): Hkv carries "model"
-    assert kp.sharding.spec[3] == "model"
+    # (n_periods, num_pages, Hkv, page, Dh): Hkv carries "model"
+    assert kp.sharding.spec[2] == "model"
     wq = eng.params["periods"]["p0"]["mixer"]["wq"]["w"]
     # (n_periods, d_model, hq*dh): column-parallel -> out dim on "model"
     assert wq.sharding.spec[2] == "model"
@@ -174,7 +174,7 @@ def test_uneven_heads_degrade_to_replicated():
     eng = ServeEngine(params, cfg, max_slots=2, max_len=32, page_size=8,
                       mesh_rules=_rules())          # model axis = 4, Hkv = 2
     kp = eng.cache["periods"]["p0"]["k_pages"]
-    assert kp.sharding.spec[3] is None
+    assert kp.sharding.spec[2] is None
     for p in PROMPTS[:2]:
         eng.submit(p, max_new_tokens=3)
     done = eng.run_to_completion()

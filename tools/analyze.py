@@ -14,9 +14,7 @@ Beyond the engine cells, the report carries a ``kernel_audit`` section:
 the static Pallas-kernel auditor (``repro.analysis.kernel_audit``) runs
 its bounds / vmem / revisit / grid passes over every kernel registered
 in ``kernels/dispatch.KERNEL_REGISTRY`` x its kv_formats x the autotune
-sweep shapes — again without executing anything.  ``--vmem-warn``
-demotes vmem-budget violations to notes (the latest-jax CI leg uses it:
-block layouts may legitimately differ there, bounds/revisit may not).
+sweep shapes — again without executing anything.
 
 ANALYSIS.json is stamped with ``"schema": ANALYSIS_SCHEMA``; the gate
 refuses to clobber or trust an artifact whose stamp it does not know,
@@ -119,8 +117,7 @@ def _retrace_results(params, cfg, arch, datapath, kv_format):
     return label, [audit_engine_retrace(eng, PROMPTS, label)]
 
 
-def run_matrix(smoke: bool = False, skip_lint: bool = False,
-               vmem_warn: bool = False) -> dict:
+def run_matrix(smoke: bool = False, skip_lint: bool = False) -> dict:
     import jax
     from repro.analysis.contracts import results_to_json
     from repro.analysis.kernel_audit import audit_registry
@@ -194,21 +191,8 @@ def run_matrix(smoke: bool = False, skip_lint: bool = False,
         print(f"  lint: {len(lint)} violation(s)")
 
     # static kernel audit: every registered kernel x kv_format x sweep
-    # shape, never executed.  --vmem-warn demotes vmem failures to notes
-    # (bounds/revisit/grid stay fatal).
+    # shape, never executed
     ka = audit_registry()
-    if vmem_warn:
-        for cell in ka["kernels"].values():
-            for p in cell["passes"]:
-                if p["pass"] == "vmem" and not p["ok"]:
-                    p["notes"] += [f"vmem-warn: {v['message']}"
-                                   for v in p["violations"]]
-                    p["violations"], p["ok"] = [], True
-            cell["violation_count"] = sum(len(p["violations"])
-                                          for p in cell["passes"])
-            cell["ok"] = not cell["violation_count"]
-        ka["ok"] = all(c["ok"] for c in ka["kernels"].values())
-        ka["vmem_warn"] = True
     report["kernel_audit"] = ka
     nbad = sum(not c["ok"] for c in ka["kernels"].values())
     print(f"  kernel_audit: {len(ka['kernels'])} kernel cells, "
@@ -227,16 +211,12 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="2-cell granite subset (fast CI smoke)")
     ap.add_argument("--skip-lint", action="store_true")
-    ap.add_argument("--vmem-warn", action="store_true",
-                    help="kernel-audit vmem violations warn instead of "
-                         "failing (latest-jax CI leg)")
     ap.add_argument("--out", default=str(ROOT / "ANALYSIS.json"),
                     help="report path (default: repo-root ANALYSIS.json)")
     args = ap.parse_args(argv)
 
     check_artifact_schema(Path(args.out))     # fail loudly on unknown stamp
-    report = run_matrix(smoke=args.smoke, skip_lint=args.skip_lint,
-                        vmem_warn=args.vmem_warn)
+    report = run_matrix(smoke=args.smoke, skip_lint=args.skip_lint)
     Path(args.out).write_text(json.dumps(report, indent=2) + "\n")
 
     nvio = sum(c["violation_count"] for c in report["cells"].values()) \
