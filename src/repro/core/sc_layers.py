@@ -18,6 +18,7 @@ bitstream path) is asserted in tests/test_sc_layers.py.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, replace
 from typing import Callable
@@ -73,6 +74,18 @@ class SCQuantConfig:
 
 SC_OFF = SCQuantConfig(mode="none")
 
+
+def _scoped(fn):
+    """Trace ``fn`` under the ``sc_linear`` name scope: every operation
+    of the layer (activation and weight quantization, the matmul, the
+    rescale) carries it in its op_name, so a profile can sum the
+    projections' device time.  Trace-time metadata only."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.named_scope("sc_linear"):
+            return fn(*args, **kwargs)
+    return wrapped
+
 # HBM bound on one block of sc_linear_int_approx's partial-product
 # counts: one granite-3-2b decode lane's lm_head alone is 405 MB of them
 _COUNTS_BYTES = 256 * 2 ** 20
@@ -106,6 +119,7 @@ def init_sc_linear(key: jax.Array, in_dim: int, out_dim: int,
 # QAT path
 # ---------------------------------------------------------------------------
 
+@_scoped
 def sc_linear_qat(params: dict, x: jax.Array, cfg: SCQuantConfig) -> jax.Array:
     """Fake-quant linear: quantize activations + weights, matmul in the
     compute dtype. With mode == none this is a plain matmul."""
@@ -180,6 +194,7 @@ def _si_epilogue(int_params: dict, sum_q: jax.Array) -> jax.Array:
     return out_counts - out_bsl // 2               # back to q domain
 
 
+@_scoped
 def sc_linear_int(int_params: dict, x_q: jax.Array,
                   matmul_fn: Callable | None = None) -> jax.Array:
     """Integer datapath: x_q int8 levels @ ternary int8 weights -> int32 sum
@@ -201,6 +216,7 @@ def sc_linear_int(int_params: dict, x_q: jax.Array,
     return _si_epilogue(int_params, sum_q)
 
 
+@_scoped
 def sc_linear_int_approx(int_params: dict, x_q: jax.Array,
                          act_bsl: int,
                          spec: "ApproxBSNSpec | None" = None,
@@ -261,6 +277,7 @@ def sc_linear_int_approx(int_params: dict, x_q: jax.Array,
     return _si_epilogue(int_params, sum_q)
 
 
+@_scoped
 def sc_linear_int_from_qat(params: dict, x: jax.Array,
                            cfg: SCQuantConfig, *,
                            backend: str | None = None) -> jax.Array:

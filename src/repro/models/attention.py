@@ -284,18 +284,17 @@ def _scatter_pools(pools: dict, fmt: str, k_new: jax.Array,
                    v_new: jax.Array, put) -> dict:
     """Quantize-on-scatter: encode the new K/V rows and write every pool
     leaf through ``put(pool, values)`` (same indices for codes, scales
-    and residuals — the pools are position-parallel)."""
+    and residuals — the pools are position-parallel).  Traced under the
+    ``kv_write`` name scope."""
     out = {}
-    for name, val in (("k", k_new), ("v", v_new)):
-        qd = kv_quant(val, fmt)
-        out[f"{name}_pages"] = _pin_pool(put(pools[f"{name}_pages"],
-                                             qd["q"]))
-        if "scale" in qd:
-            out[f"{name}_scale"] = _pin_pool(put(pools[f"{name}_scale"],
-                                                 qd["scale"]))
-        if "resid" in qd:
-            out[f"{name}_resid"] = _pin_pool(put(pools[f"{name}_resid"],
-                                                 qd["resid"]))
+    with jax.named_scope("kv_write"):
+        for name, val in (("k", k_new), ("v", v_new)):
+            qd = kv_quant(val, fmt)
+            for leaf, key in (("pages", "q"), ("scale", "scale"),
+                              ("resid", "resid")):
+                if key in qd:
+                    pool = f"{name}_{leaf}"
+                    out[pool] = _pin_pool(put(pools[pool], qd[key]))
     return out
 
 
@@ -334,17 +333,19 @@ def attn_decode_paged(p: dict, x: jax.Array, cfg: ModelConfig,
 
     qg = q.reshape(S, hkv, g, dh)
     aux = _kv_aux(new_pools)
-    if current_rules() is not None:
-        # mesh path: the constrained XLA reference (KV-head axis stays
-        # "model"-sharded through the logits; see module comment above)
-        o = kernel_ref.paged_attn_decode_ref(
-            qg, new_pools["k_pages"], new_pools["v_pages"], page_tables,
-            lengths, kv_format=fmt, kv_aux=aux,
-            pin_logits=lambda lg: constrain(lg, None, "model", None, None))
-    else:
-        o = kernel_dispatch.paged_attn_decode(
-            qg, new_pools["k_pages"], new_pools["v_pages"], page_tables,
-            lengths, kv_format=fmt, kv_aux=aux)
+    with jax.named_scope("paged_attn"):
+        if current_rules() is not None:
+            # mesh path: the constrained XLA reference (KV-head axis stays
+            # "model"-sharded through the logits; see module comment above)
+            o = kernel_ref.paged_attn_decode_ref(
+                qg, new_pools["k_pages"], new_pools["v_pages"],
+                page_tables, lengths, kv_format=fmt, kv_aux=aux,
+                pin_logits=lambda lg: constrain(lg, None, "model", None,
+                                                None))
+        else:
+            o = kernel_dispatch.paged_attn_decode(
+                qg, new_pools["k_pages"], new_pools["v_pages"],
+                page_tables, lengths, kv_format=fmt, kv_aux=aux)
     o = o.reshape(S, 1, hq * dh).astype(x.dtype)
     # gather the head-sharded context BEFORE wo: the serving wo is
     # column-parallel, so its hq*dh contraction must be device-local
@@ -390,16 +391,17 @@ def attn_verify_paged(p: dict, x: jax.Array, cfg: ModelConfig,
 
     qg = q.reshape(S, T, hkv, g, dh)
     aux = _kv_aux(new_pools)
-    if current_rules() is not None:
-        o = kernel_ref.paged_attn_verify_ref(
-            qg, new_pools["k_pages"], new_pools["v_pages"], page_tables,
-            lengths, kv_format=fmt, kv_aux=aux,
-            pin_logits=lambda lg: constrain(lg, None, "model",
-                                            None, None, None))
-    else:
-        o = kernel_ref.paged_attn_verify_ref(
-            qg, new_pools["k_pages"], new_pools["v_pages"], page_tables,
-            lengths, kv_format=fmt, kv_aux=aux)
+    with jax.named_scope("paged_attn"):
+        if current_rules() is not None:
+            o = kernel_ref.paged_attn_verify_ref(
+                qg, new_pools["k_pages"], new_pools["v_pages"],
+                page_tables, lengths, kv_format=fmt, kv_aux=aux,
+                pin_logits=lambda lg: constrain(lg, None, "model",
+                                                None, None, None))
+        else:
+            o = kernel_ref.paged_attn_verify_ref(
+                qg, new_pools["k_pages"], new_pools["v_pages"],
+                page_tables, lengths, kv_format=fmt, kv_aux=aux)
     o = o.reshape(S, T, hq * dh).astype(x.dtype)
     o = constrain(o, None, None, None)
     y = dense_apply(p["wo"], o, cfg.quant)
@@ -448,16 +450,17 @@ def attn_prefill_paged(p: dict, x: jax.Array, cfg: ModelConfig,
 
     qg = q.reshape(G, C, hkv, g, dh)
     aux = _kv_aux(new_pools)
-    if current_rules() is not None:
-        o = kernel_ref.paged_attn_prefill_ref(
-            qg, new_pools["k_pages"], new_pools["v_pages"], page_tables,
-            start, kv_format=fmt, kv_aux=aux,
-            pin_logits=lambda lg: constrain(lg, None, "model",
-                                            None, None, None))
-    else:
-        o = kernel_dispatch.paged_attn_prefill(
-            qg, new_pools["k_pages"], new_pools["v_pages"], page_tables,
-            start, kv_format=fmt, kv_aux=aux)
+    with jax.named_scope("paged_attn"):
+        if current_rules() is not None:
+            o = kernel_ref.paged_attn_prefill_ref(
+                qg, new_pools["k_pages"], new_pools["v_pages"],
+                page_tables, start, kv_format=fmt, kv_aux=aux,
+                pin_logits=lambda lg: constrain(lg, None, "model",
+                                                None, None, None))
+        else:
+            o = kernel_dispatch.paged_attn_prefill(
+                qg, new_pools["k_pages"], new_pools["v_pages"],
+                page_tables, start, kv_format=fmt, kv_aux=aux)
     o = o.reshape(G, C, hq * dh).astype(x.dtype)
     o = constrain(o, None, None, None)      # see attn_decode_paged
     y = dense_apply(p["wo"], o, cfg.quant)

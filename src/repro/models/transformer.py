@@ -675,8 +675,11 @@ def paged_decode_step(params: dict, cache: dict, tokens: jax.Array,
                 for k, v in ce.items()}
         return x, new_entries
 
-    x, new_periods = jax.lax.scan(period_body, x,
-                                  (params["periods"], cache["periods"]))
+    # the layer loop is named, so a profile can place the operations XLA
+    # adds to it (copies of the pools it slices) without a scope of their own
+    with jax.named_scope("layers"):
+        x, new_periods = jax.lax.scan(
+            period_body, x, (params["periods"], cache["periods"]))
     x = norm_apply(params["final_norm"], x, cfg.norm)
     logits = dense_apply(params["lm_head"], x, cfg.quant)
     logits = logits + _vocab_bias(cfg, logits.dtype)
@@ -739,8 +742,9 @@ def paged_verify_step(params: dict, cache: dict, tokens: jax.Array,
                                 if k not in _POOL_KEYS}
         return x, (new_entries, snaps)
 
-    x, (new_periods, snaps) = jax.lax.scan(
-        period_body, x, (params["periods"], cache["periods"]))
+    with jax.named_scope("layers"):
+        x, (new_periods, snaps) = jax.lax.scan(
+            period_body, x, (params["periods"], cache["periods"]))
     x = norm_apply(params["final_norm"], x, cfg.norm)
     logits = dense_apply(params["lm_head"], x, cfg.quant)
     logits = logits + _vocab_bias(cfg, logits.dtype)
@@ -902,9 +906,10 @@ def paged_prefill(params: dict, cache: dict, tokens: jax.Array,
                     _group_state_specs(cfg, idx))
             return (x, pools), new_gs
 
-        (xc, pools), gstate = jax.lax.scan(
-            period_body, (xc, pools),
-            (params["periods"], gstate, layer_ids))
+        with jax.named_scope("layers"):
+            (xc, pools), gstate = jax.lax.scan(
+                period_body, (xc, pools),
+                (params["periods"], gstate, layer_ids))
         # keep the hidden state of each request's last real token
         last = prompt_lens - 1 - start
         rws = jnp.take_along_axis(
@@ -913,9 +918,10 @@ def paged_prefill(params: dict, cache: dict, tokens: jax.Array,
                            rws, h_last)
         return (pools, gstate, h_last), None
 
-    (pools, gstate, h_last), _ = jax.lax.scan(
-        chunk_body, (pools, gstate, h_last),
-        jnp.arange(L // chunk, dtype=jnp.int32))
+    with jax.named_scope("chunks"):
+        (pools, gstate, h_last), _ = jax.lax.scan(
+            chunk_body, (pools, gstate, h_last),
+            jnp.arange(L // chunk, dtype=jnp.int32))
 
     # scatter each lane's final carry into its slot's state rows (padded
     # lanes land in the scratch row, whose contents no live request

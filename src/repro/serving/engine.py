@@ -91,6 +91,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.kv_quant import kv_quant
@@ -119,6 +120,20 @@ __all__ = ["Request", "SamplingParams", "ServeEngine", "EngineConfig",
 # matching the one-request oracle.
 STRICT_ROUNDING = {"xla_allow_excess_precision": False}
 _jit = partial(jax.jit, compiler_options=STRICT_ROUNDING)
+
+# What ServeEngine.stats counts, as plain integers since construction
+# (serving/README.md, "Reading the engine"); spec_stats is the view of
+# the spec_* ones.
+COUNTERS = ("steps", "decode_steps", "decode_lanes", "decode_lanes_padded",
+            "prefill_groups", "prefill_tokens", "prefill_tokens_padded",
+            "admitted", "preempted", "truncated", "finished",
+            "queue_depth_peak", "spec_rounds", "spec_draft_tokens",
+            "spec_accepted_tokens", "spec_emitted_tokens")
+
+
+def _rids(reqs) -> str:
+    """Request ids as a span's metadata (TraceMe splits on commas)."""
+    return " ".join(str(r.rid) for r in reqs)
 
 
 def _cfg_for_datapath(cfg: ModelConfig, datapath: str) -> ModelConfig:
@@ -195,8 +210,7 @@ class ServeEngine:
         self.spec_decode = config.spec_decode
         self.draft_len = config.draft_len
         self.cfg_draft = _cfg_for_datapath(cfg, "sc_int_approx")
-        self._spec_rounds = self._spec_draft_tokens = 0
-        self._spec_accepted = self._spec_emitted = 0
+        self._n = dict.fromkeys(COUNTERS, 0)
         self.max_slots, self.max_len = config.max_slots, config.max_len
         self.page_size = config.page_size
         self.max_pages = pages_needed(config.max_len, config.page_size)
@@ -308,14 +322,20 @@ class ServeEngine:
     # snapshot.  The lp slot is an EMPTY tuple then, so output pytrees
     # and out_shardings stay aligned across both variants.
 
+    def _pick(self, logits, positions, samp, do_sample):
+        """The in-jit token pick of every traced body."""
+        with jax.named_scope("sampler"):
+            if do_sample:
+                return sample_tokens(logits, positions, samp,
+                                     self.cfg.vocab_size)
+            return greedy_tokens(logits, self.cfg.vocab_size)
+
     def _decode_fn(self, params, cache, tokens, slot_ids, tables, lengths,
                    samp, *, do_sample, lp_k=0):
         logits, cache = paged_decode_step(params, cache, tokens,
                                           slot_ids, tables, lengths,
                                           self.cfg)
-        nxt = sample_tokens(logits, lengths + 1, samp,
-                            self.cfg.vocab_size) if do_sample \
-            else greedy_tokens(logits, self.cfg.vocab_size)
+        nxt = self._pick(logits, lengths + 1, samp, do_sample)
         lp = token_logprobs(logits, nxt, samp, self.cfg.vocab_size,
                             lp_k) if lp_k else ()
         return nxt, cache, lp
@@ -325,9 +345,7 @@ class ServeEngine:
         logits, cache = paged_prefill(params, cache, tokens, tables,
                                       lens, self.cfg, chunk=chunk,
                                       slot_ids=slot_ids)
-        nxt = sample_tokens(logits, lens, samp,
-                            self.cfg.vocab_size) if do_sample \
-            else greedy_tokens(logits, self.cfg.vocab_size)
+        nxt = self._pick(logits, lens, samp, do_sample)
         lp = token_logprobs(logits, nxt, samp, self.cfg.vocab_size,
                             lp_k) if lp_k else ()
         return nxt, cache, lp
@@ -337,9 +355,7 @@ class ServeEngine:
         logits, cache = prefill(params, batch, self.cfg)
         plen = logits.shape[1]                    # static: exact length
         pos = jnp.full((1,), plen, jnp.int32)
-        tok = sample_tokens(logits[:, -1], pos, samp,
-                            self.cfg.vocab_size) if do_sample \
-            else greedy_tokens(logits[:, -1], self.cfg.vocab_size)
+        tok = self._pick(logits[:, -1], pos, samp, do_sample)
         lp = token_logprobs(logits[:, -1], tok, samp,
                             self.cfg.vocab_size, lp_k) if lp_k else ()
         return tok[0], cache, lp
@@ -377,9 +393,7 @@ class ServeEngine:
             logits, cache = paged_decode_step(params, cache, tok,
                                               slot_ids, tables,
                                               lengths + t, self.cfg_draft)
-            nxt = sample_tokens(logits, lengths + 1 + t, samp,
-                                self.cfg.vocab_size) if do_sample \
-                else greedy_tokens(logits, self.cfg.vocab_size)
+            nxt = self._pick(logits, lengths + 1 + t, samp, do_sample)
             return (cache, nxt), nxt
 
         (cache, _), drafts = jax.lax.scan(
@@ -400,10 +414,7 @@ class ServeEngine:
         pos = (lengths[:, None] + 1
                + jnp.arange(T, dtype=jnp.int32)[None, :]).reshape(-1)
         sampf = {k: jnp.repeat(v, T) for k, v in samp.items()}
-        tau = sample_tokens(flat, pos, sampf,
-                            self.cfg.vocab_size) if do_sample \
-            else greedy_tokens(flat, self.cfg.vocab_size)
-        tau = tau.reshape(S, T)
+        tau = self._pick(flat, pos, sampf, do_sample).reshape(S, T)
         m = speculative_accept(drafts, tau[:, :T - 1])    # (S,)
         cache = scatter_state_rows(
             cache, select_state_snapshot(snaps, m), slot_ids)
@@ -458,7 +469,12 @@ class ServeEngine:
         r = Request(next(self._rid), list(prompt), max_new_tokens, eos_id,
                     sampling if sampling is not None else SamplingParams())
         self.queue.append(r)
+        self._note_queue()
         return r.rid
+
+    def _note_queue(self):
+        self._n["queue_depth_peak"] = max(self._n["queue_depth_peak"],
+                                          len(self.queue))
 
     def _free_slot(self) -> int | None:
         for i, s in enumerate(self.slots):
@@ -468,28 +484,31 @@ class ServeEngine:
 
     # -- admission ------------------------------------------------------
     def _admit(self):
-        group: list[tuple[int, Request]] = []
-        while self.queue:
-            slot = self._free_slot()
-            if slot is None:
-                break
-            req = self.queue[0]
-            table = PageTable(self.page_size)
-            # reserve prompt pages + the first decode write up front
-            if not table.ensure(len(req.prompt) + 1, self.allocator):
-                break                         # pool pressure: wait
-            self.queue.pop(0)
-            req._table, req._len = table, len(req.prompt)
-            self.slots[slot] = req
-            group.append((slot, req))
-        if not group:
-            return
-        if supports_paged_prefill(self.cfg) \
-                and self.prefill_mode == "chunked":
-            self._prefill_group(group)
-        else:
-            for _, r in group:
-                self._prefill_one(r)
+        with TraceAnnotation("engine.admit") as span:
+            group: list[tuple[int, Request]] = []
+            while self.queue:
+                slot = self._free_slot()
+                if slot is None:
+                    break
+                req = self.queue[0]
+                table = PageTable(self.page_size)
+                # reserve prompt pages + the first decode write up front
+                if not table.ensure(len(req.prompt) + 1, self.allocator):
+                    break                         # pool pressure: wait
+                self.queue.pop(0)
+                req._table, req._len = table, len(req.prompt)
+                self.slots[slot] = req
+                group.append((slot, req))
+            if not group:
+                return
+            self._n["admitted"] += len(group)
+            span.set_metadata(rids=_rids(r for _, r in group))
+            if supports_paged_prefill(self.cfg) \
+                    and self.prefill_mode == "chunked":
+                self._prefill_group(group)
+            else:
+                for _, r in group:
+                    self._prefill_one(r)
 
     def _prefill_group(self, group: list[tuple[int, Request]]):
         """Batched chunked prefill: one padded (G, L) bucket.  Like the
@@ -498,37 +517,47 @@ class ServeEngine:
         changes; padded lanes are all-trash tables + zero lengths +
         the scratch state row."""
         reqs = [r for _, r in group]
+        rids = _rids(reqs)
         plens = [len(r.prompt) for r in reqs]
         G = pad_pow2(len(reqs), hi=self.max_slots)
         L = pad_pow2(max(plens), lo=self.page_size)
-        chunk = min(self._chunk, L)
-        width = pad_pow2(max(L // self.page_size,
-                             max(len(r._table.pages) for r in reqs)))
-        tokens = np.zeros((G, L), np.int32)
-        tables = np.full((G, width), TRASH_PAGE, np.int32)
-        lens = np.zeros((G,), np.int32)
-        slot_ids = np.full((G,), self.max_slots, np.int32)   # scratch row
-        for g, (slot, r) in enumerate(group):
-            tokens[g, :plens[g]] = r.prompt
-            tables[g] = r._table.padded(width)
-            lens[g] = plens[g]
-            slot_ids[g] = slot
-        samp = pack_sampling([r.sampling for r in reqs], pad_to=G)
-        do_sample = any(not r.sampling.greedy for r in reqs)
-        lp_k = self._lp_bucket(reqs)
-        with self._scope():
-            nxt, self.cache, lp = self._prefill_batched(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(tables), jnp.asarray(lens),
-                jnp.asarray(slot_ids), samp, chunk=chunk,
-                do_sample=do_sample, lp_k=lp_k)
-        lp = jax.device_get(lp) if lp_k else None
-        for g, r in enumerate(reqs):
-            r.generated.append(int(nxt[g]))
-            if lp is not None and r.sampling.logprobs > 0:
-                r.logprobs.append(self._lp_record(
-                    lp[0][g], lp[1][g], lp[2][g], r.sampling.logprobs))
-            self._check_done(r)
+        self._count_prefill(plens, G * L)
+        with TraceAnnotation("engine.prefill", rids=rids):
+            chunk = min(self._chunk, L)
+            width = pad_pow2(max(L // self.page_size,
+                                 max(len(r._table.pages) for r in reqs)))
+            tokens = np.zeros((G, L), np.int32)
+            tables = np.full((G, width), TRASH_PAGE, np.int32)
+            lens = np.zeros((G,), np.int32)
+            slot_ids = np.full((G,), self.max_slots, np.int32)  # scratch
+            for g, (slot, r) in enumerate(group):
+                tokens[g, :plens[g]] = r.prompt
+                tables[g] = r._table.padded(width)
+                lens[g] = plens[g]
+                slot_ids[g] = slot
+            samp = pack_sampling([r.sampling for r in reqs], pad_to=G)
+            do_sample = any(not r.sampling.greedy for r in reqs)
+            lp_k = self._lp_bucket(reqs)
+            with self._scope():
+                nxt, self.cache, lp = self._prefill_batched(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(tables), jnp.asarray(lens),
+                    jnp.asarray(slot_ids), samp, chunk=chunk,
+                    do_sample=do_sample, lp_k=lp_k)
+        with TraceAnnotation("engine.prefill.sync", rids=rids):
+            lp = jax.device_get(lp) if lp_k else None
+            for g, r in enumerate(reqs):
+                r.generated.append(int(nxt[g]))
+                if lp is not None and r.sampling.logprobs > 0:
+                    r.logprobs.append(self._lp_record(
+                        lp[0][g], lp[1][g], lp[2][g], r.sampling.logprobs))
+                self._check_done(r)
+
+    def _count_prefill(self, plens, padded: int):
+        n = self._n
+        n["prefill_groups"] += 1
+        n["prefill_tokens"] += sum(plens)
+        n["prefill_tokens_padded"] += padded
 
     def _check_done(self, r: Request):
         """THE stop rule (the only copy: prefill and decode both route
@@ -550,20 +579,24 @@ class ServeEngine:
         chunked path token for token, which the tests assert — and (b)
         the route for frontend archs, whose inputs aren't token
         prompts (``supports_paged_prefill`` is False)."""
-        toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        samp = pack_sampling([req.sampling])
-        lp_k = self._lp_bucket([req])
-        with self._scope():
-            tok, cache_one, lp = self._prefill_exact(
-                self.params, {"tokens": toks}, samp,
-                do_sample=not req.sampling.greedy, lp_k=lp_k)
-        self._scatter_prefill(req, cache_one)
-        req.generated.append(int(tok))
-        if lp_k and req.sampling.logprobs > 0:
-            lp = jax.device_get(lp)
-            req.logprobs.append(self._lp_record(
-                lp[0][0], lp[1][0], lp[2][0], req.sampling.logprobs))
-        self._check_done(req)
+        rids = _rids([req])
+        self._count_prefill([len(req.prompt)], len(req.prompt))
+        with TraceAnnotation("engine.prefill", rids=rids):
+            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            samp = pack_sampling([req.sampling])
+            lp_k = self._lp_bucket([req])
+            with self._scope():
+                tok, cache_one, lp = self._prefill_exact(
+                    self.params, {"tokens": toks}, samp,
+                    do_sample=not req.sampling.greedy, lp_k=lp_k)
+            self._scatter_prefill(req, cache_one)
+        with TraceAnnotation("engine.prefill.sync", rids=rids):
+            req.generated.append(int(tok))
+            if lp_k and req.sampling.logprobs > 0:
+                lp = jax.device_get(lp)
+                req.logprobs.append(self._lp_record(
+                    lp[0][0], lp[1][0], lp[2][0], req.sampling.logprobs))
+            self._check_done(req)
 
     def _scatter_prefill(self, req: Request, cache_one: dict):
         """Write a (B=1, exact-length) prefill cache into pages/rows.
@@ -661,14 +694,17 @@ class ServeEngine:
                 if not victims:
                     # nothing left to evict: finish truncated
                     r.done = True
+                    self._n["truncated"] += 1
                     break
                 v = victims[-1]
+                self._n["preempted"] += 1
                 vr = self.slots[v]
                 vr._table.release(self.allocator)
                 vr._table, vr._len = None, 0
                 vr.generated = []
                 vr.logprobs = []
                 self.queue.insert(0, vr)
+                self._note_queue()
                 self.slots[v] = None
                 active.remove(v)
         return [i for i in active
@@ -681,6 +717,7 @@ class ServeEngine:
                 r._table = None
                 done.append(r)
                 self.slots[i] = None
+                self._n["finished"] += 1
 
     def _step_batch(self, active: list[int]):
         """The shared (Sb, maxp) pow2-bucketed lane tensors every decode
@@ -727,33 +764,75 @@ class ServeEngine:
         Emitted tokens are always the target's own (seed, position) draws
         (see the traced-body comment), so requests cannot tell this path
         from plain decode — only the step count can."""
-        tokens, slot_ids, tables, lengths, samp, do_sample, lp_k = \
-            self._step_batch(active)
-        with self._scope():
+        n = self._n
+        with TraceAnnotation("engine.spec.prepare"):
+            tokens, slot_ids, tables, lengths, samp, do_sample, lp_k = \
+                self._step_batch(active)
+        with TraceAnnotation("engine.spec.dispatch"), self._scope():
             drafts, self.cache = self._draft(
                 self.params, self.cache, tokens, slot_ids, tables,
                 lengths, samp, do_sample=do_sample)
             tau, m, self.cache, lp = self._verify(
                 self.params, self.cache, tokens, drafts, slot_ids,
                 tables, lengths, samp, do_sample=do_sample, lp_k=lp_k)
-        tau, m = np.asarray(tau), np.asarray(m)
-        lp = jax.device_get(lp) if lp_k else None
-        self._spec_rounds += 1
-        self._spec_draft_tokens += self.draft_len * len(active)
-        for lane, i in enumerate(active):
-            r = self.slots[i]
-            self._spec_accepted += int(m[lane])
-            for j in range(int(m[lane]) + 1):
-                r.generated.append(int(tau[lane, j]))
+        with TraceAnnotation("engine.spec.sync"):
+            tau, m = np.asarray(tau), np.asarray(m)
+            lp = jax.device_get(lp) if lp_k else None
+        n["spec_rounds"] += 1
+        n["spec_draft_tokens"] += self.draft_len * len(active)
+        with TraceAnnotation("engine.spec.commit"):
+            for lane, i in enumerate(active):
+                r = self.slots[i]
+                n["spec_accepted_tokens"] += int(m[lane])
+                for j in range(int(m[lane]) + 1):
+                    r.generated.append(int(tau[lane, j]))
+                    r._len += 1
+                    if lp is not None and r.sampling.logprobs > 0:
+                        r.logprobs.append(self._lp_record(
+                            lp[0][lane, j], lp[1][lane, j],
+                            lp[2][lane, j], r.sampling.logprobs))
+                    n["spec_emitted_tokens"] += 1
+                    self._check_done(r)
+                    if r.done:
+                        break
+
+    def _decode_round(self, active: list[int]):
+        """One batched decode step: every active lane takes one token."""
+        n = self._n
+        with TraceAnnotation("engine.decode.prepare"):
+            tokens, slot_ids, tables, lengths, samp, do_sample, lp_k = \
+                self._step_batch(active)
+        with TraceAnnotation("engine.decode.dispatch"), self._scope():
+            nxt, self.cache, lp = self._decode(
+                self.params, self.cache, tokens, slot_ids, tables,
+                lengths, samp, do_sample=do_sample, lp_k=lp_k)
+        with TraceAnnotation("engine.decode.sync"):
+            nxt = np.asarray(nxt)
+            lp = jax.device_get(lp) if lp_k else None
+        n["decode_steps"] += 1
+        n["decode_lanes"] += len(active)
+        n["decode_lanes_padded"] += len(nxt)
+        with TraceAnnotation("engine.decode.commit"):
+            for lane, i in enumerate(active):
+                r = self.slots[i]
+                r.generated.append(int(nxt[lane]))
                 r._len += 1
                 if lp is not None and r.sampling.logprobs > 0:
                     r.logprobs.append(self._lp_record(
-                        lp[0][lane, j], lp[1][lane, j], lp[2][lane, j],
+                        lp[0][lane], lp[1][lane], lp[2][lane],
                         r.sampling.logprobs))
-                self._spec_emitted += 1
                 self._check_done(r)
-                if r.done:
-                    break
+
+    @property
+    def stats(self) -> dict:
+        """The engine's counters since construction (``COUNTERS``), plus
+        the page pool's ``pages_in_use_peak`` and ``pages_total`` (pages
+        it can hand out, the trash page excluded).  ``*_padded`` count
+        the bucketed shapes the device ran: prefill ``G x L`` per group,
+        decode ``Sb`` lanes per step; speculative rounds count under
+        ``spec_*``, not ``decode_*``."""
+        return {**self._n, "pages_in_use_peak": self.allocator.peak_in_use,
+                "pages_total": self.allocator.capacity}
 
     @property
     def spec_stats(self) -> dict:
@@ -763,53 +842,46 @@ class ServeEngine:
         verifier-side speedup (each round costs ONE target-model
         multi-token step, so this is the decode-steps-saved factor on
         hardware where the drafter is cheap)."""
+        n = self._n
         return {
-            "rounds": self._spec_rounds,
-            "draft_tokens": self._spec_draft_tokens,
-            "accepted_tokens": self._spec_accepted,
-            "emitted_tokens": self._spec_emitted,
-            "acceptance_rate": (self._spec_accepted
-                                / max(self._spec_draft_tokens, 1)),
-            "tokens_per_round": (self._spec_emitted
-                                 / max(self._spec_rounds, 1)),
+            "rounds": n["spec_rounds"],
+            "draft_tokens": n["spec_draft_tokens"],
+            "accepted_tokens": n["spec_accepted_tokens"],
+            "emitted_tokens": n["spec_emitted_tokens"],
+            "acceptance_rate": (n["spec_accepted_tokens"]
+                                / max(n["spec_draft_tokens"], 1)),
+            "tokens_per_round": (n["spec_emitted_tokens"]
+                                 / max(n["spec_rounds"], 1)),
         }
 
     def step(self) -> list[Request]:
         """Admit + ONE batched decode step (speculative round when
         ``spec_decode`` is on and every lane has window headroom).
-        Returns finished requests."""
-        self._admit()
-        done: list[Request] = []
-        # requests finished at prefill free their pages BEFORE growth, so
-        # they are never preemption victims and their pages count toward
-        # this step's headroom
-        self._sweep_done(done)
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if self.spec_decode and active \
-                and self._ensure_spec_window(active):
-            self._spec_round(active)
-        else:
-            active = self._grow_or_preempt(active)
-            if active:
-                tokens, slot_ids, tables, lengths, samp, do_sample, \
-                    lp_k = self._step_batch(active)
-                with self._scope():
-                    nxt, self.cache, lp = self._decode(
-                        self.params, self.cache, tokens, slot_ids,
-                        tables, lengths, samp, do_sample=do_sample,
-                        lp_k=lp_k)
-                nxt = np.asarray(nxt)
-                lp = jax.device_get(lp) if lp_k else None
-                for lane, i in enumerate(active):
-                    r = self.slots[i]
-                    r.generated.append(int(nxt[lane]))
-                    r._len += 1
-                    if lp is not None and r.sampling.logprobs > 0:
-                        r.logprobs.append(self._lp_record(
-                            lp[0][lane], lp[1][lane], lp[2][lane],
-                            r.sampling.logprobs))
-                    self._check_done(r)
-        self._sweep_done(done)          # decode-finished + truncated
+        Returns finished requests.  Each phase runs in a host span
+        (``engine.*``, serving/README.md) on the profiler's clock."""
+        with TraceAnnotation("engine.step"):
+            self._n["steps"] += 1
+            self._admit()
+            done: list[Request] = []
+            # requests finished at prefill free their pages BEFORE growth,
+            # so they are never preemption victims and their pages count
+            # toward this step's headroom
+            with TraceAnnotation("engine.sweep"):
+                self._sweep_done(done)
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            spec = False
+            if self.spec_decode and active:
+                with TraceAnnotation("engine.spec.grow"):
+                    spec = self._ensure_spec_window(active)
+            if spec:
+                self._spec_round(active)
+            else:
+                with TraceAnnotation("engine.grow"):
+                    active = self._grow_or_preempt(active)
+                if active:
+                    self._decode_round(active)
+            with TraceAnnotation("engine.sweep"):
+                self._sweep_done(done)      # decode-finished + truncated
         return done
 
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:

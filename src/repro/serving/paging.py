@@ -102,7 +102,9 @@ class PageAllocator:
     Page 0 (``TRASH_PAGE``) is reserved at construction and never
     allocated.  ``alloc`` is all-or-nothing: it either returns ``n``
     distinct pages or ``None`` (so admission can fall back to waiting /
-    preemption without partial bookkeeping).
+    preemption without partial bookkeeping).  ``capacity`` (pages it can
+    hand out, the trash page excluded) and ``peak_in_use`` (the most
+    ever held at once) are the memory manager's readings.
     """
 
     def __init__(self, num_pages: int):
@@ -113,10 +115,15 @@ class PageAllocator:
         # keeps the hot working set of physical pages small
         self._free = list(range(num_pages - 1, 0, -1))
         self._allocated: set[int] = set()
+        self.peak_in_use = 0
 
     @property
     def free_count(self) -> int:
         return len(self._free)
+
+    @property
+    def capacity(self) -> int:
+        return self.num_pages - 1
 
     def alloc(self, n: int) -> list[int] | None:
         if n < 0:
@@ -125,6 +132,7 @@ class PageAllocator:
             return None
         pages = [self._free.pop() for _ in range(n)]
         self._allocated.update(pages)
+        self.peak_in_use = max(self.peak_in_use, len(self._allocated))
         return pages
 
     def free(self, pages: list[int]) -> None:

@@ -111,7 +111,9 @@ def test_fragmentation_interleaved_alloc_free_to_exhaustion():
     starting capacity (no page leaked, none minted)."""
     cap = 16
     a = PageAllocator(cap + 1)
+    assert a.capacity == cap
     held = []
+    peak = 0
     rng = np.random.default_rng(5)
     for _ in range(200):
         if held and rng.integers(3) == 0:
@@ -126,12 +128,15 @@ def test_fragmentation_interleaved_alloc_free_to_exhaustion():
         assert len(set(owned)) == len(owned)
         assert TRASH_PAGE not in owned
         assert a.free_count + len(owned) == cap
+        peak = max(peak, len(owned))
+        assert a.peak_in_use == peak
     while (got := a.alloc(1)) is not None:   # exhaust
         held.append(got)
     assert a.free_count == 0 and a.alloc(1) is None
     for g in held:
         a.free(g)
     assert a.free_count == cap
+    assert a.peak_in_use == cap
 
 
 def test_alloc_fail_leaves_pool_intact():
@@ -321,6 +326,13 @@ def test_preemption_under_page_pressure():
     ref = sequential_generate(params, CFG, prompts, max_new_tokens=12,
                               max_len=24)
     assert got == ref
+    st = eng.stats
+    # the counters see it: a victim re-admitted after its preemption,
+    # the whole pool held at the peak, nothing truncated
+    assert st["preempted"] >= 1
+    assert st["admitted"] == len(prompts) + st["preempted"]
+    assert st["pages_in_use_peak"] == st["pages_total"] == 4
+    assert st["truncated"] == 0 and st["finished"] == len(prompts)
 
 
 # ---------------------------------------------------------------------------
