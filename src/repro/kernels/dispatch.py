@@ -246,14 +246,15 @@ def attn_backend_scope(backend: str | None) -> Iterator[None]:
 
 def paged_attn_decode(q: jax.Array, k_pages: jax.Array,
                       v_pages: jax.Array, page_tables: jax.Array,
-                      lengths: jax.Array, *, backend: str | None = None,
+                      lengths: jax.Array, *, layer=None,
+                      backend: str | None = None,
                       num_splits: int = 1,
                       min_rows_for_kernel: int = 8,
                       kv_format: str = "fp",
                       kv_aux: dict | None = None) -> jax.Array:
     """Batched one-token paged decode: (S, Hkv, G, D) queries against the
-    (N, Hkv, page, D) pools through (S, maxp) tables, masked by
-    ``lengths``.  Flash-decoding Pallas kernel on the kernel backends,
+    (N, Hkv, page, D) pools — or layer ``layer`` of an (L, N, Hkv, page,
+    D) stack — through (S, maxp) tables, masked by ``lengths``.  Flash-decoding Pallas kernel on the kernel backends,
     XLA gather oracle (kernels/ref.py) on ``"reference"``.  Compressed
     pools (``kv_format`` "int8"/"sc") pass the parallel scale/residual
     pools in ``kv_aux`` (keys ``k_scale``/``v_scale``[/``k_resid``/
@@ -266,10 +267,11 @@ def paged_attn_decode(q: jax.Array, k_pages: jax.Array,
     _resolved[("paged_attn_decode", chosen)] += 1
     if chosen == "reference":
         return ref.paged_attn_decode_ref(q, k_pages, v_pages,
-                                         page_tables, lengths,
+                                         page_tables, lengths, layer=layer,
                                          kv_format=kv_format, kv_aux=aux)
     return paged_attn_decode_pallas(q, k_pages, v_pages, page_tables,
-                                    lengths, num_splits=num_splits,
+                                    lengths, layer=layer,
+                                    num_splits=num_splits,
                                     interpret=chosen == "pallas-interpret",
                                     kv_format=kv_format, **aux)
 
@@ -361,7 +363,10 @@ def _bsn_temporal_cases() -> tuple[tuple[str, dict], ...]:
 def _decode_cases() -> tuple[tuple[str, dict], ...]:
     # the bench_serving autotune shapes: the serving-scale decode point
     # and the longer-context split-K point (pools sized like
-    # autotune._paged_case: S * maxp pages + the reserved trash page)
+    # autotune._paged_case: S * maxp pages + the reserved trash page),
+    # on one layer's pools; then the decode step's stacks of 3 layers'
+    # pools (the layer scalar), at pages of 16 and at pages of 128,
+    # where the kernel reads the (D, page) view
     cases = []
     for tag, maxp, splits in (("serving", 4, (1, 2, 4)),
                               ("long", 16, (1, 2, 4, 8))):
@@ -371,6 +376,11 @@ def _decode_cases() -> tuple[tuple[str, dict], ...]:
             cases.append((f"{tag}_maxp{maxp}_splits{s}",
                           dict(S=8, Hkv=2, G=2, D=16, page=16, maxp=maxp,
                                num_pages=8 * maxp + 1, num_splits=s)))
+    for page, maxp in ((16, 4), (128, 2)):
+        cases.append((f"stack3_page{page}_maxp{maxp}",
+                      dict(S=4, Hkv=2, G=2, D=16, page=page, maxp=maxp,
+                           num_pages=4 * maxp + 1, num_splits=2,
+                           num_layers=3)))
     return tuple(cases)
 
 

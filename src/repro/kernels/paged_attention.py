@@ -48,6 +48,14 @@ heads, ``(1, Hkv, page)``; the kernel picks its head's row and turns it
 into a ``(page, 1)`` column with exact selects and sums (adding zeros),
 so the dequant multiplies exactly the values ``kv_dequant`` does.
 
+The decode kernel also takes the stacks ``(L, N, Hkv, page, D)`` of
+every layer's pools that the model's layer loop carries, with a
+``layer`` scalar-prefetch operand in its index maps: each grid step
+DMAs one page of one layer, and no layer is ever sliced out of the
+stack (one layer's pools are a stack of one).  Where the pools' TPU
+layout keeps the page axis minor (:func:`page_minor`) it reads the
+``(D, page)`` view that layout holds, a bitcast.
+
 Compressed KV pools (core/kv_quant.py: ``kv_format`` "int8" / "sc")
 dequantize INSIDE the kernels: the scale pools (and the sc residual
 pools) ride the same scalar-prefetch page-table index maps as the KV
@@ -80,16 +88,36 @@ _NEG = -1e30
 _QK_DIMS = (((1,), (1,)), ((), ()))
 
 
-def _head_scale_column(s_ref, h, page: int) -> jax.Array:
-    """Row ``h`` of the ``(1, Hkv, page)`` scale block as a ``(page, 1)``
-    column.  Both steps select one value and add zeros, so the column
-    holds the stored scales exactly (no matmul, no rounding)."""
-    s = s_ref[0]                                    # (Hkv, page)
+def _head_scale_row(s, h) -> jax.Array:
+    """Row ``h`` of a ``(Hkv, page)`` scale block as ``(1, page)``: one
+    value selected, zeros added, so the row holds the stored scales
+    exactly (no matmul, no rounding)."""
     heads = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    row = jnp.sum(jnp.where(heads == h, s, 0.0), axis=0, keepdims=True)
+    return jnp.sum(jnp.where(heads == h, s, 0.0), axis=0, keepdims=True)
+
+
+def _head_scale_column(s, h) -> jax.Array:
+    """Row ``h`` of a ``(Hkv, page)`` scale block as a ``(page, 1)``
+    column, by the same exact selects and sums."""
+    row = _head_scale_row(s, h)
+    page = s.shape[1]
     eye = (jax.lax.broadcasted_iota(jnp.int32, (page, page), 0)
            == jax.lax.broadcasted_iota(jnp.int32, (page, page), 1))
     return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _dequant_block(kv_format: str, raw, sc=None, resid=None) -> jax.Array:
+    """One page of codes -> float32, ``sc`` broadcasting over it.  The
+    elementwise math mirrors core.kv_quant.kv_dequant exactly, so the
+    kernels match the gather-then-dequant reference bit for bit per
+    element."""
+    if kv_format == "fp":
+        return raw.astype(jnp.float32)
+    if kv_format == "int8":
+        return raw.astype(jnp.float32) * sc
+    fused = (resid.astype(jnp.int32)
+             + raw.astype(jnp.int32) * (1 << SC_SHIFT))
+    return fused.astype(jnp.float32) * (sc * (2.0 ** -SC_SHIFT))
 
 
 def _load_kv_block(kv_format: str, h, x_ref, s_ref=None, r_ref=None):
@@ -98,19 +126,12 @@ def _load_kv_block(kv_format: str, h, x_ref, s_ref=None, r_ref=None):
 
     ``x_ref`` is the (1, 1, page, D) pool block; for compressed formats
     ``s_ref`` is the page's (1, Hkv, page) scale block and ``r_ref`` the
-    sc residual block.  The elementwise math mirrors
-    core.kv_quant.kv_dequant exactly, so the kernel matches the
-    gather-then-dequant reference bit-for-bit per element.
+    sc residual block.
     """
     raw = x_ref[0, 0]                               # (page, D)
-    if kv_format == "fp":
-        return raw.astype(jnp.float32)
-    sc = _head_scale_column(s_ref, h, raw.shape[0])   # (page, 1)
-    if kv_format == "int8":
-        return raw.astype(jnp.float32) * sc
-    fused = (r_ref[0, 0].astype(jnp.int32)
-             + raw.astype(jnp.int32) * (1 << SC_SHIFT))
-    return fused.astype(jnp.float32) * (sc * (2.0 ** -SC_SHIFT))
+    sc = None if s_ref is None else _head_scale_column(s_ref[0], h)
+    return _dequant_block(kv_format, raw, sc,
+                          None if r_ref is None else r_ref[0, 0])
 
 
 def _split_aux_refs(kv_format: str, rest, n_tail: int):
@@ -130,9 +151,24 @@ def _split_aux_refs(kv_format: str, rest, n_tail: int):
 # decode: one query row per slot, split-K over pages
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
+def page_minor(page: int, D: int) -> bool:
+    """Whether the decode kernel reads the pools' ``(D, page)`` view.
+
+    A TPU's default layout for a pool ``(..., page, D)`` whose page is a
+    multiple of the 128 lanes and whose head dim is not puts the page
+    axis minor: the pool lies in memory as ``(..., D, page)``, in whole
+    (8, 128) tiles.  The kernel then takes that swapped view, which XLA
+    lowers to a bitcast of the pool; a ``(page, D)`` operand there would
+    cost a relayout copy of every pool the kernel reads.  Everywhere
+    else the view is the pool itself.
+    """
+    return page % 128 == 0 and D % 128 != 0
+
+
+def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
                    *rest, page: int, pps: int,
-                   scale: float, kv_format: str):
+                   scale: float, kv_format: str, swapped: bool):
+    del layer_ref                                   # read by the index maps
     aux, (m_ref, l_ref, acc_ref) = _split_aux_refs(kv_format, rest, 3)
     s = pl.program_id(0)
     h = pl.program_id(1)
@@ -148,13 +184,27 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
     length = len_ref[s]
     base = (sp * pps + p) * page                    # first position in page
 
+    def load(x_ref, s_ref=None, r_ref=None):
+        # one (1, 1, 1, page, D) block -> (page, D) float32, or (D, page)
+        # from the swapped view, whose scales are a (1, page) row
+        sc = None
+        if s_ref is not None:
+            head_scales = _head_scale_row if swapped else _head_scale_column
+            sc = head_scales(s_ref[0, 0], h)
+        return _dequant_block(kv_format, x_ref[0, 0, 0], sc,
+                              None if r_ref is None else r_ref[0, 0, 0])
+
     @pl.when(base <= length)                        # page holds live tokens
     def _accumulate():
         q = q_ref[0, 0].astype(jnp.float32)         # (G, D)
-        k = _load_kv_block(kv_format, h, k_ref, *aux[0::2])  # (page, D)
-        v = _load_kv_block(kv_format, h, v_ref, *aux[1::2])
-        logits = jax.lax.dot_general(
-            q, k, _QK_DIMS, preferred_element_type=jnp.float32) / scale
+        k = load(k_ref, *aux[0::2])
+        v = load(v_ref, *aux[1::2])
+        if swapped:                                 # k, v: (D, page)
+            logits = jnp.dot(q, k, preferred_element_type=jnp.float32)
+        else:                                       # k, v: (page, D)
+            logits = jax.lax.dot_general(
+                q, k, _QK_DIMS, preferred_element_type=jnp.float32)
+        logits = logits / scale
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         live = pos <= length                        # (1, page)
         logits = jnp.where(live, logits, _NEG)      # (G, page)
@@ -163,23 +213,21 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                                             keepdims=True))
         w = jnp.where(live, jnp.exp(logits - new_m), 0.0)
         corr = jnp.exp(m_prev - new_m)
+        if swapped:
+            wv = jax.lax.dot_general(w, v, _QK_DIMS,
+                                     preferred_element_type=jnp.float32)
+        else:
+            wv = jnp.dot(w, v, preferred_element_type=jnp.float32)
         m_ref[0, 0, 0] = new_m
         l_ref[0, 0, 0] = (l_ref[0, 0, 0] * corr
                           + jnp.sum(w, axis=-1, keepdims=True))
-        acc_ref[0, 0, 0] = (acc_ref[0, 0, 0] * corr
-                            + jnp.dot(w, v,
-                                      preferred_element_type=jnp.float32))
+        acc_ref[0, 0, 0] = acc_ref[0, 0, 0] * corr + wv
 
 
-def _kv_operands(kv_format: str, num_pages: int, Hkv: int, page: int,
-                 D: int, kv_dtype, kv_index, scale_index) -> list:
+def _kv_operands(kv_format: str, kv: dict, sc: dict) -> list:
     """The pool operands in kernel order: K, V [, scales [, residuals]].
-    KV/residual blocks are one head's page ``(1, 1, page, D)``; a scale
-    block is one page for all heads ``(1, Hkv, page)``."""
-    kv = dict(shape=(num_pages, Hkv, page, D), dtype=kv_dtype,
-              block=(1, 1, page, D), index_map=kv_index)
-    sc = dict(shape=(num_pages, Hkv, page), dtype=jnp.float32,
-              block=(1, Hkv, page), index_map=scale_index)
+    ``kv`` describes the KV/residual pools (one head's page per block),
+    ``sc`` the scale pools (one page for all heads per block)."""
     ops = [BlockOperand("k_pages", **kv), BlockOperand("v_pages", **kv)]
     if kv_format != "fp":
         ops += [BlockOperand("k_scale", **sc),
@@ -193,17 +241,20 @@ def _kv_operands(kv_format: str, num_pages: int, Hkv: int, page: int,
 def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
                            page: int, maxp: int, num_pages: int,
                            num_splits: int = 1, kv_format: str = "fp",
-                           q_dtype=jnp.float32,
-                           kv_dtype=None) -> LaunchPlan:
+                           q_dtype=jnp.float32, kv_dtype=None,
+                           num_layers: int = 1) -> LaunchPlan:
     """Static launch geometry of the flash-decoding decode kernel.
 
     Single source of truth for the grid, BlockSpecs and scalar-prefetch
     operands: :func:`paged_attn_decode_pallas` executes exactly this
     plan, and the static auditor (``repro.analysis.kernel_audit``)
     proves its bounds/VMEM/revisit properties without tracing it.
-    ``num_pages`` is the pool's leading dim (engine pools include the
+    ``num_pages`` is the pool's page dim (engine pools include the
     reserved trash page), bounding legal page-table entries; ragged
-    worst-case lengths straddle the last page boundary.  The per-split
+    worst-case lengths straddle the last page boundary.  The pools are
+    a stack of ``num_layers`` layers' pools and the ``layer`` scalar
+    picks one, so every grid step DMAs one page of one layer: the
+    caller never slices a layer out of the stack.  The per-split
     partials ``m``/``l`` are ``(G, 1)`` columns and ``acc`` is
     ``(G, D)``: full trailing dims, legal for every ``num_splits``.
     """
@@ -213,24 +264,30 @@ def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
     num_splits = max(1, min(num_splits, maxp))
     pps = -(-maxp // num_splits)                    # pages per split
     maxp_pad = num_splits * pps                     # trash-padded lanes
+    swapped = page_minor(page, D)
 
     kernel = functools.partial(_decode_kernel, page=page, pps=pps,
-                               scale=math.sqrt(D), kv_format=kv_format)
+                               scale=math.sqrt(D), kv_format=kv_format,
+                               swapped=swapped)
 
-    def kv_index(s, h, sp, p, pt, ln):
+    def kv_index(s, h, sp, p, pt, ln, ly):
         del ln
-        return (pt[s, sp * pps + p], h, 0, 0)
+        return (ly[0], pt[s, sp * pps + p], h, 0, 0)
 
-    def scale_index(s, h, sp, p, pt, ln):
+    def scale_index(s, h, sp, p, pt, ln, ly):
         del h, ln
-        return (pt[s, sp * pps + p], 0, 0)
+        return (ly[0], pt[s, sp * pps + p], 0, 0)
 
+    tile = (D, page) if swapped else (page, D)
+    kv = dict(shape=(num_layers, num_pages, Hkv) + tile, dtype=kv_dtype,
+              block=(1, 1, 1) + tile, index_map=kv_index)
+    sc = dict(shape=(num_layers, num_pages, Hkv, page), dtype=jnp.float32,
+              block=(1, 1, Hkv, page), index_map=scale_index)
     inputs = [BlockOperand("q", (S, Hkv, G, D), q_dtype, (1, 1, G, D),
-                           lambda s, h, sp, p, pt, ln: (s, h, 0, 0))]
-    inputs += _kv_operands(kv_format, num_pages, Hkv, page, D, kv_dtype,
-                           kv_index, scale_index)
+                           lambda s, h, sp, p, pt, ln, ly: (s, h, 0, 0))]
+    inputs += _kv_operands(kv_format, kv, sc)
 
-    part_index = lambda s, h, sp, p, pt, ln: (s, h, sp, 0, 0)  # noqa: E731
+    part_index = lambda s, h, sp, p, pt, ln, ly: (s, h, sp, 0, 0)  # noqa: E731
     max_len = maxp * page
     return LaunchPlan(
         name="paged_attn_decode",
@@ -246,6 +303,8 @@ def paged_attn_decode_plan(*, S: int, Hkv: int, G: int, D: int,
                           values=(max_len - page, max_len - page + 1,
                                   max(0, max_len - page - 1)),
                           kernel_only=True),
+            ScalarOperand("layer", (1,), jnp.int32,
+                          max_value=num_layers - 1),
         ),
         inputs=tuple(inputs),
         outputs=(
@@ -281,7 +340,8 @@ def _aux_operands(kv_format: str, k_scale, v_scale, k_resid, v_resid):
                    static_argnames=("num_splits", "interpret", "kv_format"))
 def paged_attn_decode_pallas(q: jax.Array, k_pages: jax.Array,
                              v_pages: jax.Array, page_tables: jax.Array,
-                             lengths: jax.Array, *, num_splits: int = 1,
+                             lengths: jax.Array, *, layer=None,
+                             num_splits: int = 1,
                              interpret: bool = False,
                              kv_format: str = "fp",
                              k_scale: jax.Array | None = None,
@@ -290,32 +350,49 @@ def paged_attn_decode_pallas(q: jax.Array, k_pages: jax.Array,
                              v_resid: jax.Array | None = None) -> jax.Array:
     """Batched one-token paged decode.
 
-    q: (S, Hkv, G, D) grouped queries; k_pages/v_pages: (N, Hkv, page, D)
-    pools (already holding the new token at position ``lengths``);
-    page_tables: (S, maxp) int32; lengths: (S,) int32.  For compressed
-    pools (``kv_format`` "int8"/"sc") the parallel ``k_scale``/``v_scale``
-    (N, Hkv, page) — and for sc the ``k_resid``/``v_resid`` — pools ride
-    the SAME page-table index maps as the KV blocks, so each grid step
-    DMAs one page of codes + its scales and dequantizes in VMEM: no fp
-    pages ever materialize in HBM.  Returns the attention context
+    q: (S, Hkv, G, D) grouped queries; k_pages/v_pages: one layer's
+    (N, Hkv, page, D) pools, or the stack (L, N, Hkv, page, D) of every
+    layer's pools with ``layer`` the int32 index of the one to read
+    (traced: the model's layer loop passes its counter, and the kernel
+    reads that layer's pages straight out of the stack).  The pools
+    already hold the new token at position ``lengths``; page_tables:
+    (S, maxp) int32; lengths: (S,) int32.  For compressed pools
+    (``kv_format`` "int8"/"sc") the parallel ``k_scale``/``v_scale``
+    ((L,) N, Hkv, page) — and for sc the ``k_resid``/``v_resid`` — pools
+    ride the SAME page-table index maps as the KV blocks, so each grid
+    step DMAs one page of codes + its scales and dequantizes in VMEM: no
+    fp pages ever materialize in HBM.  Returns the attention context
     (S, Hkv, G, D) in q.dtype.
     """
     check_kv_format(kv_format)
     S, Hkv, G, D = q.shape
-    page = k_pages.shape[2]
+    aux_ops = _aux_operands(kv_format, k_scale, v_scale, k_resid, v_resid)
+    if k_pages.ndim == 4:                 # one layer's pools: a stack of one
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        aux_ops = [a[None] for a in aux_ops]
+        layer = 0
+    assert layer is not None, "a stack of pools needs its layer index"
+    num_layers, num_pages, _, page, _ = k_pages.shape
     maxp = page_tables.shape[1]
     plan = paged_attn_decode_plan(
         S=S, Hkv=Hkv, G=G, D=D, page=page, maxp=maxp,
-        num_pages=k_pages.shape[0], num_splits=num_splits,
-        kv_format=kv_format, q_dtype=q.dtype, kv_dtype=k_pages.dtype)
+        num_pages=num_pages, num_splits=num_splits,
+        kv_format=kv_format, q_dtype=q.dtype, kv_dtype=k_pages.dtype,
+        num_layers=num_layers)
     maxp_pad = plan.scalars[0].shape[1]
     if maxp_pad != maxp:
         # pad table lanes with the trash page: they sit past ``lengths``
         # (which is < maxp*page by construction) so masking kills them
         page_tables = jnp.pad(page_tables, ((0, 0), (0, maxp_pad - maxp)))
+    if page_minor(page, D):
+        # the view the pools' TPU layout already holds (a bitcast there)
+        k_pages, v_pages = (jnp.swapaxes(a, -1, -2) for a in (k_pages,
+                                                             v_pages))
+        aux_ops = [jnp.swapaxes(a, -1, -2) if a.ndim == 5 else a
+                   for a in aux_ops]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    aux_ops = _aux_operands(kv_format, k_scale, v_scale, k_resid, v_resid)
-    m, l, acc = call_plan(plan, (page_tables, lengths, q, k_pages,
+    m, l, acc = call_plan(plan, (page_tables, lengths, layer, q, k_pages,
                                  v_pages, *aux_ops), interpret=interpret)
     m, l = m[..., 0], l[..., 0]                       # (S, Hkv, splits, G)
 
@@ -423,8 +500,12 @@ def paged_attn_prefill_plan(*, G: int, C: int, Hkv: int, Gq: int, D: int,
 
     inputs = [BlockOperand("q", (G * Hq, C, D), q_dtype, (1, bq, D),
                            lambda bh, qi, pg, pt, st: (bh, qi, 0))]
-    inputs += _kv_operands(kv_format, num_pages, Hkv, page, D, kv_dtype,
-                           kv_index, scale_index)
+    inputs += _kv_operands(
+        kv_format,
+        dict(shape=(num_pages, Hkv, page, D), dtype=kv_dtype,
+             block=(1, 1, page, D), index_map=kv_index),
+        dict(shape=(num_pages, Hkv, page), dtype=jnp.float32,
+             block=(1, Hkv, page), index_map=scale_index))
 
     return LaunchPlan(
         name="paged_attn_prefill",

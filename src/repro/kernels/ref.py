@@ -68,20 +68,27 @@ def from_pages(x: jax.Array, axis: int = 1) -> jax.Array:
     return x.reshape(*x.shape[:axis], -1, *x.shape[axis + 2:])
 
 
-def gather_pages(pages: jax.Array, page_tables: jax.Array) -> jax.Array:
+def gather_pages(pages: jax.Array, page_tables: jax.Array,
+                 layer=None) -> jax.Array:
     """Head-major (N, Hkv, page, ...) pool + (S, maxp) tables ->
     position-major (S, maxp*page, Hkv, ...).
 
     Works for KV pools (N, Hkv, page, Dh) and their parallel scale pools
-    (N, Hkv, page) alike — the trailing axes ride along unchanged.
+    (N, Hkv, page) alike — the trailing axes ride along unchanged.  With
+    ``layer``, ``pages`` is a stack (L, N, ...) of every layer's pools
+    and one gather reads that layer's pages out of it.
     """
-    g = jnp.take(pages, page_tables, axis=0)        # (S, maxp, Hkv, page, …)
+    if layer is None:
+        g = jnp.take(pages, page_tables, axis=0)    # (S, maxp, Hkv, page, …)
+    else:
+        g = pages[layer, page_tables]
     return from_pages(g)
 
 
 def gather_pages_dequant(pages: jax.Array, page_tables: jax.Array, *,
                          kv_format: str = "fp", scale: jax.Array | None = None,
-                         resid: jax.Array | None = None) -> jax.Array:
+                         resid: jax.Array | None = None,
+                         layer=None) -> jax.Array:
     """Gather + dequantize a compressed pool window in one step.
 
     Gather commutes with the elementwise dequant, so dequantizing the
@@ -89,23 +96,26 @@ def gather_pages_dequant(pages: jax.Array, page_tables: jax.Array, *,
     without ever materializing fp pages.  Zero-filled positions (trash
     page, unwritten tail) dequantize to exact 0 in every format.
     """
-    g = gather_pages(pages, page_tables)
+    g = gather_pages(pages, page_tables, layer)
     if kv_format == "fp":
         return g
-    sg = gather_pages(scale, page_tables)
-    rg = gather_pages(resid, page_tables) if kv_format == "sc" else None
+    sg = gather_pages(scale, page_tables, layer)
+    rg = (gather_pages(resid, page_tables, layer) if kv_format == "sc"
+          else None)
     return kv_dequant(g, sg, rg, fmt=kv_format)
 
 
 def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, page_tables: jax.Array,
-                          lengths: jax.Array, *, pin_logits=None,
-                          kv_format: str = "fp",
+                          lengths: jax.Array, *, layer=None,
+                          pin_logits=None, kv_format: str = "fp",
                           kv_aux: dict | None = None) -> jax.Array:
     """XLA gather/scatter paged decode — the paged-kernel ground truth.
 
     q: (S, Hkv, G, D); pools: (N, Hkv, page, D) already holding the new
-    token at position ``lengths``; page_tables: (S, maxp) int32;
+    token at position ``lengths`` — or, with ``layer``, the stack
+    (L, N, Hkv, page, D) of every layer's pools, as the kernel takes
+    them; page_tables: (S, maxp) int32;
     lengths: (S,) int32.  Gathers each slot's full ``maxp*page`` KV
     window, masks positions past ``lengths`` and softmaxes — positions
     in padded table lanes point at the trash page but sit past the
@@ -121,10 +131,11 @@ def paged_attn_decode_ref(q: jax.Array, k_pages: jax.Array,
     aux = kv_aux or {}
     kg = gather_pages_dequant(k_pages, page_tables, kv_format=kv_format,
                               scale=aux.get("k_scale"),
-                              resid=aux.get("k_resid"))  # (S, T, Hkv, Dh)
+                              resid=aux.get("k_resid"),
+                              layer=layer)              # (S, T, Hkv, Dh)
     vg = gather_pages_dequant(v_pages, page_tables, kv_format=kv_format,
                               scale=aux.get("v_scale"),
-                              resid=aux.get("v_resid"))
+                              resid=aux.get("v_resid"), layer=layer)
     T = kg.shape[1]
     logits = jnp.einsum("shgd,sthd->shgt", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) / math.sqrt(D)

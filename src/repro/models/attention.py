@@ -273,15 +273,17 @@ def attn_decode(p: dict, x: jax.Array, cfg: ModelConfig,
 _AUX_KEYS = ("k_scale", "v_scale", "k_resid", "v_resid")
 
 
-def _pin_pool(a: jax.Array) -> jax.Array:
+def _pin_pool(a: jax.Array, head_axis: int = 1) -> jax.Array:
     """Pools stay KV-head-sharded across steps (weights-resident layout);
     scatter indices are replicated, so the update is device-local.  Works
-    for KV/resid pools (N, Hkv, page, Dh) and scale pools (N, Hkv, page)."""
-    return constrain(a, None, "model", *(None,) * (a.ndim - 2))
+    for KV/resid pools (N, Hkv, page, Dh) and scale pools (N, Hkv, page),
+    and for stacks of them (``head_axis`` 2)."""
+    return constrain(a, *(None,) * head_axis, "model",
+                     *(None,) * (a.ndim - head_axis - 1))
 
 
 def _scatter_pools(pools: dict, fmt: str, k_new: jax.Array,
-                   v_new: jax.Array, put) -> dict:
+                   v_new: jax.Array, put, head_axis: int = 1) -> dict:
     """Quantize-on-scatter: encode the new K/V rows and write every pool
     leaf through ``put(pool, values)`` (same indices for codes, scales
     and residuals — the pools are position-parallel).  Traced under the
@@ -294,8 +296,31 @@ def _scatter_pools(pools: dict, fmt: str, k_new: jax.Array,
                               ("resid", "resid")):
                 if key in qd:
                     pool = f"{name}_{leaf}"
-                    out[pool] = _pin_pool(put(pools[pool], qd[key]))
+                    out[pool] = _pin_pool(put(pools[pool], qd[key]),
+                                          head_axis)
     return out
+
+
+def _stack_put(layer, phys: jax.Array, off: jax.Array):
+    """``put`` for :func:`_scatter_pools` into layer ``layer`` of the
+    pool stacks the decode step carries: (L, N, Hkv, page, Dh) codes /
+    residuals, (L, N, Hkv, page) scales; lane s's values (Hkv[, Dh])
+    land at page ``phys[s]``, offset ``off[s]``.
+
+    One dynamic-update-slice per lane, which XLA applies in place in
+    whatever layout the stack is carried.  A scatter does not: on a TPU
+    a scatter of (Hkv, Dh) windows relayouts the whole stack so that the
+    window dims lie minor, and a scatter of single values runs value by
+    value.
+    """
+    def put(pool, val):
+        val = val.astype(pool.dtype)
+        for s in range(val.shape[0]):
+            row = val[s][None, None, :, None]       # (1, 1, Hkv, 1[, Dh])
+            start = (layer, phys[s], 0, off[s]) + (0,) * (pool.ndim - 4)
+            pool = jax.lax.dynamic_update_slice(pool, row, start)
+        return pool
+    return put
 
 
 def _kv_aux(pools: dict) -> dict:
@@ -307,13 +332,18 @@ def attn_decode_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     """Batched one-token decode over the paged KV cache.
 
     x: (S, 1, D) — one new token per active slot; ``pools`` holds the
-    (N, Hkv, page, Dh) KV pools + (S, maxp) int32 ``page_tables`` (+ any
-    scale/resid leaves); lengths: (S,) int32 tokens already in the cache
-    (== the new token's position).  Returns (y (S, 1, D), new_pools) —
-    the updated pool leaves, page_tables excluded.
+    stacks (L, N, Hkv, page, Dh) of every layer's KV pools (+ any
+    scale/resid stacks), as the decode step's layer loop carries them,
+    the int32 ``layer`` this call reads and writes, and (S, maxp) int32
+    ``page_tables``; lengths: (S,) int32 tokens already in the cache
+    (== the new token's position).  The new K/V go into layer ``layer``
+    in place and the kernel reads that layer out of the stack, so no
+    layer's pool is ever sliced or copied.  Returns (y (S, 1, D),
+    new_pools) — the updated stacks, page_tables and layer excluded.
     """
     page_tables = pools["page_tables"]
-    page = pools["k_pages"].shape[2]
+    page = pools["k_pages"].shape[3]
+    layer = pools["layer"]
     fmt = kv_format_of(pools)
     S = x.shape[0]
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -326,10 +356,8 @@ def attn_decode_paged(p: dict, x: jax.Array, cfg: ModelConfig,
     phys = jnp.take_along_axis(page_tables, (lengths // page)[:, None],
                                axis=1)[:, 0]
     off = lengths % page
-    new_pools = _scatter_pools(
-        pools, fmt, k[:, 0], v[:, 0],
-        lambda pool, val: pool.at[phys, :, off].set(
-            val.astype(pool.dtype)))
+    new_pools = _scatter_pools(pools, fmt, k[:, 0], v[:, 0],
+                               _stack_put(layer, phys, off), head_axis=2)
 
     qg = q.reshape(S, hkv, g, dh)
     aux = _kv_aux(new_pools)
@@ -339,13 +367,15 @@ def attn_decode_paged(p: dict, x: jax.Array, cfg: ModelConfig,
             # "model"-sharded through the logits; see module comment above)
             o = kernel_ref.paged_attn_decode_ref(
                 qg, new_pools["k_pages"], new_pools["v_pages"],
-                page_tables, lengths, kv_format=fmt, kv_aux=aux,
+                page_tables, lengths, layer=layer, kv_format=fmt,
+                kv_aux=aux,
                 pin_logits=lambda lg: constrain(lg, None, "model", None,
                                                 None))
         else:
             o = kernel_dispatch.paged_attn_decode(
                 qg, new_pools["k_pages"], new_pools["v_pages"],
-                page_tables, lengths, kv_format=fmt, kv_aux=aux)
+                page_tables, lengths, layer=layer, kv_format=fmt,
+                kv_aux=aux)
     o = o.reshape(S, 1, hq * dh).astype(x.dtype)
     # gather the head-sharded context BEFORE wo: the serving wo is
     # column-parallel, so its hq*dh contraction must be device-local

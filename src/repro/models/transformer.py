@@ -637,6 +637,17 @@ _POOL_KEYS = ("k_pages", "v_pages", "k_scale", "v_scale",
               "k_resid", "v_resid")
 
 
+def _split_pools(cache: dict, cfg: ModelConfig) -> tuple[dict, dict]:
+    """The cache's period entries split into the page-pool stacks and the
+    per-slot state rows, each ``{"p<i>": {leaf: (n_periods, ...)}}``."""
+    pools, rows = {}, {}
+    for i in range(len(cfg.period)):
+        pe = cache["periods"][f"p{i}"]
+        pools[f"p{i}"] = {k: v for k, v in pe.items() if k in _POOL_KEYS}
+        rows[f"p{i}"] = {k: v for k, v in pe.items() if k not in _POOL_KEYS}
+    return pools, rows
+
+
 def paged_decode_step(params: dict, cache: dict, tokens: jax.Array,
                       slot_ids: jax.Array, page_tables: jax.Array,
                       lengths: jax.Array, cfg: ModelConfig):
@@ -656,30 +667,39 @@ def paged_decode_step(params: dict, cache: dict, tokens: jax.Array,
     x = jnp.take(params["embed"]["table"], tokens[:, None], axis=0)  # (S,1,D)
     x = constrain(x, None, None, None)   # embed table is vocab-sharded
 
-    def period_body(x, inp):
-        pp, cper = inp
-        new_entries = {}
+    pools, rows = _split_pools(cache, cfg)
+    layer_ids = jnp.arange(cfg.n_periods, dtype=jnp.int32)
+
+    def period_body(carry, inp):
+        # the pool stacks ride the carry whole: each attention position
+        # scatters its new K/V into layer ``li`` in place and its kernel
+        # reads layer ``li`` out of the stack, so no layer's pool is
+        # sliced out or copied back.  State rows stay the loop's xs/ys.
+        x, pools = carry
+        pp, rper, li = inp
+        pools, new_rows = dict(pools), {}
         for idx, spec in enumerate(cfg.period):
-            entry = cper[f"p{idx}"]
-            cst = {k: (v if k in _POOL_KEYS
-                       else jax.tree.map(lambda a: a[slot_ids], v))
-                   for k, v in entry.items()}
+            key = f"p{idx}"
+            cst = {k: jax.tree.map(lambda a: a[slot_ids], v)
+                   for k, v in rper[key].items()}
+            cst.update(pools[key])
             cst["page_tables"] = page_tables
-            x, _, ce = _apply_position(pp[f"p{idx}"], spec, x, cfg,
+            cst["layer"] = li
+            x, _, ce = _apply_position(pp[key], spec, x, cfg,
                                        None, "decode", cst, lengths)
-            new_entries[f"p{idx}"] = {
-                k: (v if k in _POOL_KEYS
-                    else jax.tree.map(
-                        lambda full, rows: full.at[slot_ids].set(rows),
-                        entry[k], v))
-                for k, v in ce.items()}
-        return x, new_entries
+            pools[key] = {k: ce[k] for k in pools[key]}
+            new_rows[key] = {
+                k: jax.tree.map(
+                    lambda full, rw: full.at[slot_ids].set(rw), v, ce[k])
+                for k, v in rper[key].items()}
+        return (x, pools), new_rows
 
     # the layer loop is named, so a profile can place the operations XLA
-    # adds to it (copies of the pools it slices) without a scope of their own
+    # adds to it without a scope of their own
     with jax.named_scope("layers"):
-        x, new_periods = jax.lax.scan(
-            period_body, x, (params["periods"], cache["periods"]))
+        (x, pools), rows = jax.lax.scan(
+            period_body, (x, pools), (params["periods"], rows, layer_ids))
+    new_periods = {key: {**pools[key], **rows[key]} for key in pools}
     x = norm_apply(params["final_norm"], x, cfg.norm)
     logits = dense_apply(params["lm_head"], x, cfg.quant)
     logits = logits + _vocab_bias(cfg, logits.dtype)
@@ -854,12 +874,7 @@ def paged_prefill(params: dict, cache: dict, tokens: jax.Array,
     # split the cache: shared page pools ride the chunk loop's carry;
     # per-slot state rows are untouched until the final scatter (prompt
     # state starts from zero, never from a previous occupant's rows)
-    pools, rows = {}, {}
-    for i in range(len(cfg.period)):
-        pe = cache["periods"][f"p{i}"]
-        pools[f"p{i}"] = {k: v for k, v in pe.items() if k in _POOL_KEYS}
-        rows[f"p{i}"] = {k: v for k, v in pe.items()
-                         if k not in _POOL_KEYS}
+    pools, rows = _split_pools(cache, cfg)
     has_state = len(jax.tree_util.tree_leaves(rows)) > 0
     if has_state and slot_ids is None:
         raise ValueError("recurrent periods need slot_ids to place their "

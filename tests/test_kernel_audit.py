@@ -14,6 +14,8 @@ must catch these from geometry alone.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax.numpy as jnp
 import pytest
 
@@ -21,6 +23,7 @@ from repro.analysis.kernel_audit import (audit_registry, run_plan_audits,
                                          scalar_sets)
 from repro.analysis.lint import hygiene_repo, hygiene_scan
 from repro.kernels.dispatch import KERNEL_REGISTRY
+from repro.kernels.paged_attention import paged_attn_decode_plan
 from repro.kernels.plan import (BlockOperand, LaunchPlan, ScalarOperand,
                                 estimate_vmem)
 
@@ -257,6 +260,49 @@ def test_grid_catches_block_rank_and_size():
                      lambda p, tbl: (p, 0, 0)),))
     vios = _only_fails(bad, "grid")
     assert "block dim" in vios[0].message
+
+
+# ---------------------------------------------------------------------------
+# the decode kernel's layer scalar
+# ---------------------------------------------------------------------------
+
+def _decode_plan(num_layers, page=16, fmt="int8"):
+    return paged_attn_decode_plan(S=4, Hkv=2, G=2, D=16, page=page, maxp=4,
+                                  num_pages=17, num_splits=2,
+                                  kv_format=fmt, num_layers=num_layers)
+
+
+@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("num_layers", [1, 5])
+def test_decode_layer_scalar_proved_in_bounds(num_layers, page):
+    """The stacked decode plan pins its ``layer`` scalar at 0 and
+    ``L - 1``, every pool operand reads through it, and all four passes
+    hold; ``num_layers=1`` is the one-layer (4-D pools) form."""
+    plan = _decode_plan(num_layers, page)
+    layer = {s.name: s for s in plan.scalars}["layer"]
+    assert layer.shape == (1,) and not layer.kernel_only
+    assert layer.max_value == num_layers - 1
+    assert {int(f["layer"][0]) for f in scalar_sets(plan)} \
+        == {0, num_layers - 1}
+    pools = [op for op in plan.inputs if op.name != "q"]
+    assert pools and all(op.shape[0] == num_layers for op in pools)
+    byname = _passes(plan)
+    assert all(r.ok for r in byname.values()), \
+        {k: [v.message for v in r.violations] for k, r in byname.items()}
+
+
+def test_bounds_catches_layer_past_the_stack():
+    """An index map that reads one layer past ``layer`` walks off the
+    stack at the scalar's max fill; only the bounds pass fires."""
+    plan = _decode_plan(3)                  # 2 splits of 2 pages
+    past = BlockOperand(
+        "k_pages", plan.inputs[1].shape, jnp.int8, plan.inputs[1].block,
+        lambda s, h, sp, p, pt, ln, ly: (ly[0] + 1, pt[s, sp * 2 + p], h,
+                                         0, 0))
+    assert plan.inputs[1].name == "k_pages"
+    ops = (plan.inputs[0], past) + plan.inputs[2:]
+    vios = _only_fails(dataclasses.replace(plan, inputs=ops), "bounds")
+    assert any("k_pages" in v.message for v in vios)
 
 
 # ---------------------------------------------------------------------------

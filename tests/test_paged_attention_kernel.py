@@ -20,6 +20,7 @@ import pytest
 
 from repro.configs import get_arch
 from repro.core.bsn import default_approx_spec
+from repro.core.kv_quant import kv_quant
 from repro.kernels import dispatch, ref
 from repro.kernels.paged_attention import (paged_attn_decode_pallas,
                                            paged_attn_prefill_pallas)
@@ -105,6 +106,99 @@ def test_decode_kernel_trash_page_poison_invisible():
                                     tables, lengths, num_splits=2,
                                     interpret=True)
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(pois))
+
+
+def _stacked_case(seed, fmt, L, S, Hkv, D, page, maxp):
+    """Pools of ``L`` layers stacked (L, N, Hkv, page, D), the layout the
+    decode step carries, quantized positionwise for compressed formats;
+    the aux dict holds the stacked scale / residual pools."""
+    rng = np.random.default_rng(seed)
+    n = S * maxp + 1
+    shape = (L, n, Hkv, page, D)
+    kf = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vf = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    aux = {}
+    if fmt == "fp":
+        kp, vp = kf, vf
+    else:
+        kq, vq = kv_quant(kf, fmt), kv_quant(vf, fmt)
+        kp, vp = kq["q"], vq["q"]
+        aux = {"k_scale": kq["scale"], "v_scale": vq["scale"]}
+        if fmt == "sc":
+            aux |= {"k_resid": kq["resid"], "v_resid": vq["resid"]}
+    tables = np.zeros((S, maxp), np.int32)
+    for s in range(S):
+        tables[s] = 1 + s * maxp + rng.permutation(maxp)
+    q = jnp.asarray(rng.standard_normal((S, Hkv, 2, D)), jnp.float32)
+    lengths = jnp.asarray(rng.integers(0, maxp * page, S), jnp.int32)
+    return q, kp, vp, jnp.asarray(tables), lengths, aux
+
+
+@pytest.mark.parametrize("page", [8, 128])      # 128: the (D, page) view
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("fmt", ["fp", "int8", "sc"])
+def test_decode_kernel_layer_index_equals_sliced_layer(fmt, layer, page):
+    """The layer-indexed kernel on a stack of pools reads exactly layer
+    ``layer``: bit for bit the 4-D kernel on ``pools[layer]``, and the
+    reference with ``layer`` is bit for bit the reference on the slice."""
+    L = 3
+    li = 0 if layer == "first" else L - 1
+    q, kp, vp, tables, lengths, aux = _stacked_case(
+        li + page, fmt, L, S=3, Hkv=2, D=16, page=page, maxp=3)
+    one = {k: v[li] for k, v in aux.items()}
+    got = paged_attn_decode_pallas(q, kp, vp, tables, lengths,
+                                   layer=jnp.int32(li), num_splits=2,
+                                   interpret=True, kv_format=fmt, **aux)
+    want = paged_attn_decode_pallas(q, kp[li], vp[li], tables, lengths,
+                                    num_splits=2, interpret=True,
+                                    kv_format=fmt, **one)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got_ref = ref.paged_attn_decode_ref(q, kp, vp, tables, lengths,
+                                        layer=jnp.int32(li), kv_format=fmt,
+                                        kv_aux=aux)
+    want_ref = ref.paged_attn_decode_ref(q, kp[li], vp[li], tables,
+                                         lengths, kv_format=fmt,
+                                         kv_aux=one)
+    np.testing.assert_array_equal(np.asarray(got_ref), np.asarray(want_ref))
+    # and the kernel agrees with the reference, as on one layer
+    np.testing.assert_allclose(np.asarray(got), np.asarray(got_ref),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("page", [8, 128])
+@pytest.mark.parametrize("fmt", ["fp", "int8", "sc"])
+def test_attn_decode_on_stacks_equals_one_layer(fmt, page):
+    """One decode attention on the layer loop's pool stacks: the new K/V
+    land in layer ``li`` of the stack in place (every other layer kept
+    bit for bit) and the output is bit for bit the same call on a stack
+    of that layer alone; the padded lane writes the trash page."""
+    from repro.models.attention import attn_decode_paged, attn_init
+    cfg = CFG.scaled(dtype="float32")
+    p = attn_init(jax.random.key(3), cfg)
+    L, li, S, maxp = 3, 1, 3, 2
+    Hkv, D = cfg.n_kv_heads, cfg.head_dim
+    _, kp, vp, tables, _, aux = _stacked_case(
+        page + 1, fmt, L, S=S, Hkv=Hkv, D=D, page=page, maxp=maxp)
+    tables = tables.at[S - 1].set(0)                # a padded lane
+    lengths = jnp.asarray([page - 1, page + 3, 0], jnp.int32)
+    x = jax.random.normal(jax.random.key(4), (S, 1, cfg.d_model))
+    stack = dict(aux, k_pages=kp, v_pages=vp)
+    one = {k: v[li:li + 1] for k, v in stack.items()}
+    with dispatch.attn_backend_scope(KERNEL):
+        y, new = attn_decode_paged(
+            p, x, cfg, dict(stack, page_tables=tables, layer=jnp.int32(li)),
+            lengths)
+        y1, new1 = attn_decode_paged(
+            p, x, cfg, dict(one, page_tables=tables, layer=jnp.int32(0)),
+            lengths)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y1))
+    assert set(new) == set(stack)
+    for k in stack:
+        got = np.asarray(new[k])
+        np.testing.assert_array_equal(got[li], np.asarray(new1[k])[0], k)
+        keep = [i for i in range(L) if i != li]
+        np.testing.assert_array_equal(got[keep], np.asarray(stack[k])[keep],
+                                      k)
 
 
 @pytest.mark.parametrize("G,Hkv,Gq,D,page,C,start", [
