@@ -90,8 +90,8 @@ def readings(log: RunLog, counters: dict, prog: dict) -> dict:
     """The five readings of a traced window (module docstring)."""
     idle = sum(v for k, v in prog["idle_by_span"].items()
                if k.startswith("engine."))
-    least = sum(sc_proj.step_least_seconds(log.dims, log.peaks, s.admitted,
-                                           len(s.decode_lens))
+    least = sum(sc_proj.step_least_seconds(log.family, log.dims, log.peaks,
+                                           s.admitted, len(s.decode_lens))
                 for s in in_window(log))
     proj = prog["device_by_scope"].get("sc_linear")
     return {
@@ -121,7 +121,8 @@ def breakdown(workload: str, seed: int, seconds: float, peaks: dict,
                     cell.traffic_dir)
     log = RunLog(seconds=seconds, max_slots=cell.ec.max_slots,
                  page_size=cell.ec.page_size, chunk=cell.ec.prefill_chunk,
-                 kv_format=cell.ec.kv_format, dims=cell.dims, peaks=peaks)
+                 kv_format=cell.ec.kv_format, dims=cell.dims,
+                 family=cell.family, peaks=peaks)
     tdir = trace_dir or tempfile.mkdtemp(prefix="bench_breakdown_")
     before = eng.stats
     jax.profiler.start_trace(tdir)
@@ -135,7 +136,7 @@ def breakdown(workload: str, seed: int, seconds: float, peaks: dict,
                                          ec.prefill_chunk))
     prog = scopes.summarize(tdir, texts)
     out["traced"] = {"counters": counters, "decode_step_ms": step_ms(log),
-                     "trace": trace.summarize(tdir, run.KERNELS,
+                     "trace": trace.summarize(tdir, cell.kernels,
                                               run.MODULES),
                      "program": prog,
                      "readings": readings(log, counters, prog)}

@@ -9,6 +9,9 @@ element; a projection does ``2 x in x out`` operations per token, at
 the int8 peak.  Every token of the step passes every layer; the LM head
 sees the decoded lanes and, of a prompt, only its last token (the one
 that gives the first output token), as bench/cost/model.py counts.
+Which projections a layer has is the model family's to say
+(``projection_params``, ``head_params``, ``projection_io_bytes`` of a
+bench/families module; ``layer_io_bytes`` is the dense decoder's).
 Padded lanes and positions count for nothing.  The least time of a step
 is the larger of its bytes over HBM bandwidth and its operations over
 the int8 peak.
@@ -17,8 +20,6 @@ the int8 peak.
 from __future__ import annotations
 
 import math
-
-from bench.cost.model import head_params, layer_params
 
 __all__ = ["TERNARY_BYTES", "layer_io_bytes", "step_ops_bytes",
            "step_least_seconds"]
@@ -34,25 +35,26 @@ def layer_io_bytes(d) -> int:
             + 2 * (d.d + d.ff) + (d.ff + d.d))
 
 
-def step_ops_bytes(d, layer_tokens: int,
+def step_ops_bytes(fam, d, layer_tokens: int,
                    head_tokens: int) -> tuple[float, float]:
     """(operations, bytes) of one step that passes ``layer_tokens``
-    through the layers and ``head_tokens`` through the LM head."""
+    through the layers and ``head_tokens`` through the LM head;
+    ``fam`` is the model family's module, ``d`` its Dims."""
     if layer_tokens <= 0:
         return 0.0, 0.0
-    ops = 2.0 * (layer_tokens * layer_params(d)
-                 + head_tokens * head_params(d))
-    byt = ((layer_params(d) + head_params(d)) * TERNARY_BYTES
-           + layer_tokens * d.layers * layer_io_bytes(d)
+    layers, head = fam.projection_params(d), fam.head_params(d)
+    ops = 2.0 * (layer_tokens * layers + head_tokens * head)
+    byt = ((layers + head) * TERNARY_BYTES
+           + layer_tokens * fam.projection_io_bytes(d)
            + head_tokens * (d.d + d.vocab))
     return ops, byt
 
 
-def step_least_seconds(d, peaks: dict, prompt_lens, decode_lanes: int
-                       ) -> float:
+def step_least_seconds(fam, d, peaks: dict, prompt_lens,
+                       decode_lanes: int) -> float:
     """Least time of a step that admits prompts of ``prompt_lens``
     tokens and decodes ``decode_lanes`` real lanes."""
-    ops, byt = step_ops_bytes(d, sum(prompt_lens) + decode_lanes,
+    ops, byt = step_ops_bytes(fam, d, sum(prompt_lens) + decode_lanes,
                               len(prompt_lens) + decode_lanes)
     return max(byt / peaks["hbm_bytes_per_s"],
                ops / peaks["int8_ops_per_s"])

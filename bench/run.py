@@ -3,16 +3,23 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> \
         --trace <0|1>
 
+BENCHMARK.json names the cell; its files are found by name
+(bench/spec.py): the configuration file, the traffic mix
+``bench/traffic/<traffic>.json``, one ``bench/metrics/<metric>.py`` a
+metric, and the model family the configuration names,
+``bench/families/<family>.py`` (sizes, weights, plain reference,
+operation counts, kernel names; ``dense_decoder`` by default).
+
 In order: make the weights on the device from the seed (one jitted
-call); build ``ServeEngine`` from the configuration file; compile every
-program the cell's traffic can reach without running it (a checkout's
-first run compiles them, later runs load them from JAX's persistent
-cache, in ``<checkout>/.jax_cache`` unless JAX_COMPILATION_CACHE_DIR
-says otherwise); offer the traffic for
+call, the family's ``program_params``); build ``ServeEngine`` from the
+configuration file; compile every program the cell's traffic can reach
+without running it (a checkout's first run compiles them, later runs
+load them from JAX's persistent cache, in ``<checkout>/.jax_cache``
+unless JAX_COMPILATION_CACHE_DIR says otherwise); offer the traffic for
 ``--seconds``; free the engine and compare a sample of what it served
-with the plain reference (bench/check.py).  With ``--trace 1`` the
-window runs under the profiler and the per-layer metrics are printed
-instead of the end-to-end ones.
+with the family's plain reference (bench/check.py).  With ``--trace 1``
+the window runs under the profiler and the per-layer metrics are
+printed instead of the end-to-end ones.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (, ``breakdown``),
@@ -46,7 +53,6 @@ for _p in (os.path.join(_ROOT, "src"), _ROOT):
 from bench import check, spec  # noqa: E402
 from bench.serve import RunLog, run_window, warm, warm_plan  # noqa: E402
 from bench.traffic.generator import generate  # noqa: E402
-from bench.weights import Dims, program_params  # noqa: E402
 
 KERNELS = ("paged_attn_decode", "paged_attn_prefill")
 MODULES = ("prefill", "decode")
@@ -73,29 +79,6 @@ class _Compiles:
         cls._on = True
 
 
-def model_config(cf: dict):
-    """The program's ModelConfig for a configuration file: the registered
-    arch with every size the file states."""
-    from repro.configs import get_arch
-    c = cf["config"]
-    arch = get_arch(cf["arch"])
-    cfg = arch.scaled(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_head=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        rope_theta=float(c["rope_theta"]),
-        rope_fraction=c.get("partial_rotary_factor", 1.0),
-        dtype=c["torch_dtype"])
-    norm = "layernorm" if "layer_norm_eps" in c else "rmsnorm"
-    if cfg.norm != norm or cfg.tie_embeddings or cfg.padded_vocab \
-            != cf["padded_vocab"] or not cfg.ffn_gated \
-            or cfg.ffn_act != c["hidden_act"]:
-        raise ValueError(f"{cf['name']}: the file does not describe arch "
-                         f"{cf['arch']} (norm, tying, vocab padding or MLP)")
-    return cfg
-
-
 def _memory(devices) -> dict:
     out = {"peak_bytes_in_use": [], "peak_bytes_reserved": []}
     for d in devices:
@@ -107,7 +90,8 @@ def _memory(devices) -> dict:
 
 class Cell:
     """A cell's files, read once: BENCHMARK.json's entry, configuration,
-    traffic mix, the program's ModelConfig and EngineConfig."""
+    traffic mix, model family (bench/families), the program's ModelConfig
+    and EngineConfig."""
 
     def __init__(self, workload: str, root: str = _ROOT):
         from repro.serving import EngineConfig
@@ -118,23 +102,27 @@ class Cell:
         self.mix = spec.traffic_of(self.wl["traffic"],
                                    os.path.join(root, "bench"))
         self.traffic_dir = os.path.join(root, "bench", "traffic")
-        self.cfg = model_config(self.cf)
-        self.dims = Dims(self.cf["config"], self.cf["padded_vocab"])
+        self.family = spec.family(
+            self.cf.get("family", spec.DEFAULT_FAMILY),
+            os.path.join(root, "bench"))
+        self.kernels = KERNELS + tuple(self.family.KERNELS)
+        self.cfg = self.family.model_config(self.cf)
+        self.dims = self.family.Dims(self.cf["config"],
+                                     self.cf["padded_vocab"])
         self.ec = EngineConfig(**self.cf["engine"]).validate()
         self.alpha_a = self.cf["weights"]["alpha_a"]
         self.wdtype = self.cf["weights"]["dtype"]
 
     def reference(self, seed: int, act_dtype: str | None = None,
                   attention: str | None = None):
-        """The plain reference, rounding activations to the
+        """The family's plain reference, rounding activations to the
         configuration's dtype, or to ``act_dtype`` (the control)."""
-        from bench.reference.decoder import Reference
-        return Reference(self.dims, self.alpha_a, seed, self.wdtype,
-                         act_dtype or self.cf["config"]["torch_dtype"],
-                         kv_int8=self.ec.kv_format == "int8",
-                         attention=attention
-                         or self.cf["check"]["attention"],
-                         page=self.ec.page_size)
+        return self.family.Reference(
+            self.dims, self.alpha_a, seed, self.wdtype,
+            act_dtype or self.cf["config"]["torch_dtype"],
+            kv_int8=self.ec.kv_format == "int8",
+            attention=attention or self.cf["check"]["attention"],
+            page=self.ec.page_size)
 
 
 def _drain(eng):
@@ -167,7 +155,8 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool,
     reqs = generate(cell.mix, seed, seconds, dims.vocab, cell.traffic_dir)
     log = RunLog(seconds=seconds, max_slots=ec.max_slots,
                  page_size=ec.page_size, chunk=ec.prefill_chunk,
-                 kv_format=ec.kv_format, dims=dims, peaks=peaks)
+                 kv_format=ec.kv_format, dims=dims, family=cell.family,
+                 peaks=peaks)
     c_start = (_Compiles.count, _Compiles.seconds)
     tdir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     n_warm = 0
@@ -177,8 +166,8 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool,
         if engine is not None:
             _drain(engine)
             engine.params = None
-        params = program_params(seed, dims, cell.alpha_a, cell.wdtype,
-                                abstract)
+        params = cell.family.program_params(seed, dims, cell.alpha_a,
+                                            cell.wdtype, abstract)
         if engine is None:
             engine = ServeEngine.from_config(params, cell.cfg, ec)
             jax.block_until_ready((params, engine.cache))
@@ -200,7 +189,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
     if trace:
-        log.trace = trace_mod.summarize(tdir, KERNELS, MODULES)
+        log.trace = trace_mod.summarize(tdir, cell.kernels, MODULES)
         shutil.rmtree(tdir, ignore_errors=True)
     return log, engine, facts
 

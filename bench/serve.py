@@ -57,7 +57,8 @@ class RunLog:
     page_size: int = 16
     chunk: int = 64
     kv_format: str = "int8"
-    dims: object = None
+    dims: object = None                  # the family's Dims
+    family: object = None                # its module (bench/families)
     peaks: dict = field(default_factory=dict)
     trace: dict | None = None
 

@@ -8,22 +8,30 @@ metric is a file of its own, found by its name:
   and length distributions name (``bench/traffic/<process>.py``,
   ``bench/traffic/lengths/<dist>.py``, see bench/traffic/generator.py)
 * ``bench/metrics/<metric>.py``    (a ``compute(run)`` function)
+* ``bench/families/<family>.py``   (the model family a configuration
+  file names under ``family``, ``dense_decoder`` where it names none:
+  its sizes, weights, plain reference and operation counts; the
+  interface is bench/families/dense_decoder.py's docstring)
 
-so a later cell, mix or metric is a new file and a new entry, and no
-file here changes.
+so a later cell, mix, metric or architecture is a new file and a new
+entry, and no file here changes.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+import sys
 
 __all__ = ["ROOT", "BENCH", "load_benchmark", "workload", "config_of",
-           "traffic_of", "metric_fn", "metrics_for", "peaks_for"]
+           "traffic_of", "metric_fn", "metrics_for", "peaks_for",
+           "DEFAULT_FAMILY", "family"]
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+DEFAULT_FAMILY = "dense_decoder"
 
 
 def _json(path: str) -> dict:
@@ -62,6 +70,29 @@ def metric_fn(name: str, bench_dir: str = BENCH):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.compute
+
+
+@functools.lru_cache(maxsize=None)
+def _family_module(path: str):
+    spec = importlib.util.spec_from_file_location(
+        "bench_family_" + os.path.basename(path)[:-3].replace(".", "_"),
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered before it runs, as an import would, so that a family's
+    # dataclasses can resolve their own module
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(name: str, bench_dir: str = BENCH):
+    """The module ``bench/families/<name>.py``, loaded once per path."""
+    path = os.path.join(os.path.abspath(bench_dir), "families",
+                        name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"model family {name!r}: no module "
+                                f"bench/families/{name}.py")
+    return _family_module(path)
 
 
 def _reports(entry: dict, wl: str) -> bool:

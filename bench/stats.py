@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.cost import model, paged_attention
+from bench.cost import paged_attention
 from bench.serve import prefill_bucket
 
 __all__ = ["p95_ms", "in_window", "prefill_calls", "decode_calls",
@@ -24,7 +24,7 @@ def in_window(run):
 
 def prefill_calls(run, step):
     """(operations, bytes) of every paged_attn_prefill call of a step: one
-    per chunk of the admission group per layer (bench/cost)."""
+    per chunk of the admission group per attention layer (bench/cost)."""
     if not step.admitted:
         return []
     d = run.dims
@@ -32,7 +32,7 @@ def prefill_calls(run, step):
                                     run.max_slots, run.chunk)
     return [paged_attention.prefill_call(step.admitted, s, chunk, d.hq,
                                          d.hkv, d.dh, run.kv_format)
-            for s in range(0, L, chunk)] * d.layers
+            for s in range(0, L, chunk)] * d.attn_layers
 
 
 def decode_calls(run, step):
@@ -40,7 +40,7 @@ def decode_calls(run, step):
         return []
     d = run.dims
     return [paged_attention.decode_call(step.decode_lens, d.hq, d.hkv,
-                                        d.dh, run.kv_format)] * d.layers
+                                        d.dh, run.kv_format)] * d.attn_layers
 
 
 def least_seconds(run, calls) -> float:
@@ -53,8 +53,9 @@ def least_seconds(run, calls) -> float:
 
 
 def step_model_ops(run, step) -> tuple[float, float]:
-    """(prefill, decode) model operations of one step (bench/cost/model)."""
-    d = run.dims
-    pre = sum(model.prefill_ops(d, p) for p in step.admitted)
-    dec = sum(model.decode_ops(d, n) for n in step.decode_lens)
+    """(prefill, decode) model operations of one step, as the run's model
+    family counts them (bench/families)."""
+    d, fam = run.dims, run.family
+    pre = sum(fam.prefill_ops(d, p) for p in step.admitted)
+    dec = sum(fam.decode_ops(d, n) for n in step.decode_lens)
     return pre, dec

@@ -1,6 +1,7 @@
 """Each metric's arithmetic, on a synthetic event log."""
 
 import copy
+import dataclasses
 
 import pytest
 from conftest import PEAKS
@@ -8,12 +9,13 @@ from conftest import PEAKS
 from bench import spec
 from bench.cost import model, paged_attention
 from bench.serve import ReqLog, RunLog, StepLog
-from bench.weights import Dims
 
-DIMS = Dims({"num_hidden_layers": 2, "hidden_size": 64,
-             "num_attention_heads": 4, "num_key_value_heads": 2,
-             "head_dim": 16, "intermediate_size": 128, "vocab_size": 100,
-             "rms_norm_eps": 1e-6, "rope_theta": 1e4}, 128)
+DENSE = spec.family("dense_decoder")
+DIMS = DENSE.Dims({"num_hidden_layers": 2, "hidden_size": 64,
+                   "num_attention_heads": 4, "num_key_value_heads": 2,
+                   "head_dim": 16, "intermediate_size": 128,
+                   "vocab_size": 100, "rms_norm_eps": 1e-6,
+                   "rope_theta": 1e4}, 128)
 
 
 def metric(name, run):
@@ -24,7 +26,7 @@ def steady_log(stall_at=None, stall=0.0, seconds=10.0):
     """Two requests decoding one token every 10 ms; an optional host
     stall delays every step after ``stall_at``."""
     log = RunLog(seconds=seconds, max_slots=4, page_size=8, chunk=8,
-                 kv_format="int8", dims=DIMS, peaks=PEAKS)
+                 kv_format="int8", dims=DIMS, family=DENSE, peaks=PEAKS)
     a, b = ReqLog(0.0, [1] * 10, 2000), ReqLog(0.5, [1] * 20, 2000)
     log.reqs = {0: a, 1: b}
     t, shift = 0.0, 0.0
@@ -70,7 +72,8 @@ def test_a_stall_raises_itl_p95_and_lowers_output_tps():
 def test_a_censored_request_counts_in_ttft_p95():
     log = steady_log()
     base = metric("ttft_p95_ms", log)
-    late = copy.deepcopy(log)
+    # the log's family is a module, shared by the copy
+    late = dataclasses.replace(log, reqs=copy.deepcopy(log.reqs))
     for i in range(2, 40):       # never served: censored at the window end
         late.reqs[i] = ReqLog(1.0, [1] * 5, 4)
     assert metric("ttft_p95_ms", late) == pytest.approx(9000.0, rel=0.01)

@@ -6,10 +6,9 @@ import math
 import pytest
 from conftest import PEAKS
 
-from bench import scopes
+from bench import scopes, spec
 from bench.cost import model, sc_proj
 from bench.trace import reduce_events
-from bench.weights import Dims
 
 MS = 1_000_000                               # ns
 DEV = "/device:TPU:0"
@@ -137,11 +136,12 @@ def test_a_trace_without_the_benchmark_spans_is_refused():
         scopes.reduce_program(_ops(), [("engine.step", 0, 1)])
 
 
-GRANITE = Dims({"num_hidden_layers": 40, "hidden_size": 2048,
-                "num_attention_heads": 32, "num_key_value_heads": 8,
-                "head_dim": 64, "intermediate_size": 8192,
-                "vocab_size": 49155, "rms_norm_eps": 1e-6,
-                "rope_theta": 1e4}, 49408)
+DENSE = spec.family("dense_decoder")
+GRANITE = DENSE.Dims({"num_hidden_layers": 40, "hidden_size": 2048,
+                      "num_attention_heads": 32, "num_key_value_heads": 8,
+                      "head_dim": 64, "intermediate_size": 8192,
+                      "vocab_size": 49155, "rms_norm_eps": 1e-6,
+                      "rope_theta": 1e4}, 49408)
 
 
 def test_projection_least_time_at_granite_widths():
@@ -156,32 +156,34 @@ def test_projection_least_time_at_granite_widths():
         + 2 * (2048 + 8192) + (8192 + 2048)
     assert sc_proj.layer_io_bytes(GRANITE) == io
     # a decode step of 16 lanes is bound by the weights' bytes
-    ops, byt = sc_proj.step_ops_bytes(GRANITE, 16, 16)
+    ops, byt = sc_proj.step_ops_bytes(DENSE, GRANITE, 16, 16)
     assert ops == 2 * 16 * weights
     assert byt == pytest.approx(weights * math.log2(3) / 8
                                 + 16 * 40 * io + 16 * (2048 + 49155))
-    least = sc_proj.step_least_seconds(GRANITE, PEAKS, [], 16)
+    least = sc_proj.step_least_seconds(DENSE, GRANITE, PEAKS, [], 16)
     assert least == pytest.approx(byt / PEAKS["hbm_bytes_per_s"])
     assert 0.6e-3 < least < 0.65e-3
     # a 2048-token prompt is bound by the int8 peak; its LM head runs once
-    ops, _ = sc_proj.step_ops_bytes(GRANITE, 2048, 1)
+    ops, _ = sc_proj.step_ops_bytes(DENSE, GRANITE, 2048, 1)
     assert ops == 2 * (2048 * 40 * per_layer + 2048 * 49155)
-    assert sc_proj.step_least_seconds(GRANITE, PEAKS, [2048], 0) \
+    assert sc_proj.step_least_seconds(DENSE, GRANITE, PEAKS, [2048], 0) \
         == pytest.approx(ops / PEAKS["int8_ops_per_s"])
-    assert sc_proj.step_ops_bytes(GRANITE, 0, 0) == (0.0, 0.0)
+    assert sc_proj.step_ops_bytes(DENSE, GRANITE, 0, 0) == (0.0, 0.0)
 
 
 def test_the_five_readings_of_a_traced_window():
     from bench.breakdown import readings
     from bench.serve import RunLog, StepLog
-    log = RunLog(seconds=1.0, max_slots=16, dims=GRANITE, peaks=PEAKS)
+    log = RunLog(seconds=1.0, max_slots=16, dims=GRANITE, family=DENSE,
+                 peaks=PEAKS)
     log.steps = [StepLog(0.0, 0.2, admitted=[300, 500]),
                  StepLog(0.2, 0.3, decode_lens=[300, 500]),
                  StepLog(0.9, 1.1, decode_lens=[301])]    # ends outside
     counters = {"prefill_tokens": 800, "prefill_tokens_padded": 2 * 512,
                 "pages_in_use_peak": 12, "pages_total": 256}
-    least = (sc_proj.step_least_seconds(GRANITE, PEAKS, [300, 500], 0)
-             + sc_proj.step_least_seconds(GRANITE, PEAKS, [], 2))
+    least = (sc_proj.step_least_seconds(DENSE, GRANITE, PEAKS, [300, 500],
+                                        0)
+             + sc_proj.step_least_seconds(DENSE, GRANITE, PEAKS, [], 2))
     prog = {"window_s": 1.0, "step_host_ms": 3.5,
             "idle_by_span": {"bench.wait": 0.3, "engine.decode.prepare":
                              0.02, "engine.step": 0.03, "none": 0.01},

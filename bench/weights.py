@@ -44,6 +44,7 @@ class Dims:
 
     def __init__(self, c: dict, padded_vocab: int):
         self.layers = c["num_hidden_layers"]
+        self.attn_layers = self.layers         # every layer attends
         self.d = c["hidden_size"]
         self.hq = c["num_attention_heads"]
         self.hkv = c["num_key_value_heads"]
